@@ -569,11 +569,13 @@ def make_cache(
     top_k: int = 4,
     eviction: str = "lcfu",
     max_ttl: float = 3600.0,
-    backend: str = "numpy",
+    backend: Optional[str] = None,
     cluster=None,
 ) -> CortexCache:
     """``cluster`` (a ``core.clustering.ClusterConfig``) switches stage 1
-    to the clustered IVF routing (DESIGN.md §12); None = brute force."""
+    to the clustered IVF routing (DESIGN.md §12); None = brute force.
+    ``backend=None`` lets the platform choose: the Pallas kernels on TPU,
+    numpy on CPU (``kernels/platform.py``)."""
     router = None
     if cluster is not None:
         from repro.core.clustering import ClusterRouter

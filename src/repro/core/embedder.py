@@ -2,7 +2,8 @@
 
 Two implementations behind one interface:
 
-* ``ModelEmbedder`` — a real (small, e.g. qwen3-0.6b-class) JAX encoder:
+* ``ModelEmbedder`` — a real JAX encoder (qwen3-0.6b at its published
+  widths, or a shrink of it):
   byte-level tokens → transformer → masked mean-pool → L2-normalise. With
   random init it still yields a deterministic, locality-free fingerprint;
   it exists to measure the true compute cost of the embedding stage and to
@@ -67,13 +68,15 @@ class ModelEmbedder:
     def dim(self) -> int:
         return self.cfg.d_model
 
+    def tokens(self, texts: Sequence[str]) -> np.ndarray:
+        """(len(texts), max_len) int32 byte tokens, folded into vocab."""
+        return np.stack([byte_tokens(t, self.max_len) for t in texts]) \
+            % self.cfg.vocab_size
+
     def embed_batch(self, texts: Sequence[str]) -> np.ndarray:
-        toks = np.stack(
-            [byte_tokens(t % self.cfg.vocab_size if isinstance(t, int)
-                         else t, self.max_len) for t in texts]
-        ) % self.cfg.vocab_size
-        return np.asarray(self._encode(self.params, jnp.asarray(toks)),
-                          np.float32)
+        return np.asarray(
+            self._encode(self.params, jnp.asarray(self.tokens(texts))),
+            np.float32)
 
 
 class WorldEmbedder:

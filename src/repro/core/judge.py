@@ -9,10 +9,11 @@ estimate (1–10) at admission time.
   decisions with configurable TPR/FPR noise. Its *scores* are drawn from
   two calibrated beta-like distributions so threshold recalibration
   (Algorithm 1) has a real precision curve to sweep.
-* ``ModelJudge`` — a real tiny cross-encoder in JAX (prefill-only, single
-  score token — the profile that makes co-location cheap, §4.4). With
-  random weights its decisions are meaningless; it exists to measure the
-  judge's true compute footprint and to drive the co-location scheduler.
+* ``ModelJudge`` — a real cross-encoder in JAX (prefill-only, single
+  score token — the profile that makes co-location cheap, §4.4), at the
+  qwen3-0.6b published widths or a shrink. With random weights its
+  decisions are meaningless; it exists to measure the judge's true
+  compute footprint and to drive the co-location scheduler.
 """
 from __future__ import annotations
 
@@ -102,8 +103,17 @@ class OracleJudge:
         return self.world.staticity(query)
 
 
+PAIR_BUCKETS = (1, 2, 4, 8, 16, 32)  # padded pair counts: one jit each
+
+
 class ModelJudge:
-    """Tiny cross-encoder: prefill-only classification (one score)."""
+    """Cross-encoder: prefill-only classification (one score per pair).
+
+    Runs any registered config — the qwen3-0.6b judge at its published
+    widths, or a shrink of it. ``score_pairs`` pads the pair count up to
+    the next of :data:`PAIR_BUCKETS` (longer lists go in chunks of the
+    largest), so the jitted prefill compiles at most once per bucket;
+    ``shapes_compiled`` records the buckets used."""
 
     def __init__(self, cfg=None, max_len: int = 128, seed: int = 1):
         import jax
@@ -134,15 +144,25 @@ class ModelJudge:
             return jax.nn.sigmoid(logit)
 
         self._score = jax.jit(score)
-        self._jnp = jnp
+        self.shapes_compiled: set[int] = set()
 
     def score_pairs(self, queries, cached_keys) -> np.ndarray:
-        toks = np.stack([
+        toks = np.array([
             self._byte_tokens(f"{q} [SEP] {c}", self.max_len)
             for q, c in zip(queries, cached_keys)
-        ]) % self.cfg.vocab_size
-        return np.asarray(self._score(self.params, self._jnp.asarray(toks)),
-                          np.float32)
+        ], np.int32).reshape(-1, self.max_len) % self.cfg.vocab_size
+        out = []
+        top = PAIR_BUCKETS[-1]
+        for off in range(0, len(toks), top):
+            chunk = toks[off:off + top]
+            m = len(chunk)
+            size = next(b for b in PAIR_BUCKETS if b >= m)
+            self.shapes_compiled.add(size)
+            padded = np.zeros((size, self.max_len), np.int32)
+            padded[:m] = chunk
+            out.append(np.asarray(self._score(self.params, padded))[:m])
+        return np.concatenate(out).astype(np.float32) if out \
+            else np.zeros(0, np.float32)
 
     def staticity(self, query: str) -> int:
         # stable across processes (Python's hash() is salted per run,

@@ -120,12 +120,12 @@ class JudgePipeline:
     ``decisions`` supplies the scores that drive hit/miss semantics
     (``OracleJudge`` in behavioural runs, ``ModelJudge`` end to end when
     semantics-faithfulness is not required). ``compute``, when set, is a
-    ``ModelJudge`` whose ``score_pairs`` is *paid* (real tiny-LM prefill
-    through the Pallas flash-attention stack) and discarded — the
-    calibration shim. ``base_tokens`` is the virtual-time cost of one
-    unbatched judge job; by default it derives from ``judge_cfg`` via
-    :func:`judge_token_cost` (which is also how ``compute``'s config
-    prices itself when given).
+    ``ModelJudge`` whose ``score_pairs`` is *paid* (real prefill through
+    the model stack, whose attention is the pure-JAX ``nn/flash.py``)
+    and discarded — the calibration shim. ``base_tokens`` is the
+    virtual-time cost of one unbatched judge job; by default it derives
+    from ``judge_cfg`` via :func:`judge_token_cost` (which is also how
+    ``compute``'s config prices itself when given).
     """
 
     def __init__(

@@ -1,9 +1,11 @@
 """Seri — the Semantic Retrieval Index (paper §4.2).
 
 Stage 1 (coarse): exact cosine top-k over the SE embedding matrix with the
-τ_sim gate. On TPU this runs as the Pallas ``ann_topk`` kernel (brute-force
-MXU matmul — the TPU-idiomatic replacement for Faiss graph traversal, see
-DESIGN.md §3); on CPU the numpy path is bit-identical.
+τ_sim gate. The platform picks the backend (``kernels/platform.py``): on
+TPU the Pallas ``ann_topk`` family (brute-force MXU matmul — the
+TPU-idiomatic replacement for Faiss graph traversal, see DESIGN.md §3),
+on CPU the numpy path, which is also the reference the kernels are
+tested against.
 
 Stage 2 (fine): the semantic judge validates each candidate's *result*
 against the new query; the first candidate with S_lsm ≥ τ_lsm is a
@@ -25,6 +27,7 @@ from typing import Optional
 import numpy as np
 
 from repro.core.semantic_element import SemanticElement
+from repro.kernels.platform import resolve_backend
 
 
 class RowIndex:
@@ -56,7 +59,10 @@ class RowIndex:
         # max-over-shards term, not the total. Equal to last_scanned
         # for brute force and unsharded routing.
         self.last_scanned_max_shard = 0
-        # backends set these; the base dispatch only tests for presence
+        # stage-1 passes by path: with ``backend`` they say which scan
+        # actually ran (the chip smoke asserts the Pallas routed scan did)
+        self.passes = {"routed": 0, "brute": 0}
+        # the kernel backend sets these (Pallas wrappers in kernels/ops)
         self._kernel_fn = None
         self._ivf_kernel_fn = None
         self._ivf_sharded_fn = None
@@ -83,22 +89,71 @@ class RowIndex:
     def _routed_dispatch(self, q: np.ndarray, kernel_scan, routed_scan,
                          brute_scan):
         """The one stage-1 dispatch both index flavors share (the same
-        anti-drift rationale as ``topk_desc``): Pallas routed scan when
-        the backend has one and clusters exist, numpy routed scan when
-        the router is trained, brute force otherwise. Returns
-        ``(rows, scores, routed)`` — ``routed`` tells the caller to
-        apply the kernel NEG-slot row filter."""
+        anti-drift rationale as ``topk_desc``). The backend picks the
+        path and never falls back: a ``"kernel"`` index runs the Pallas
+        routed scan once clusters exist and the Pallas brute scan
+        otherwise; a ``"numpy"`` index (the reference) runs the numpy
+        routed scan once the router is trained, brute force otherwise.
+        Returns ``(rows, scores, routed)`` — ``routed`` tells the caller
+        to apply the kernel NEG-slot row filter."""
         ready = self.router is not None and self.router.ready
-        if ready and self._ivf_kernel_fn is not None and \
-                np.any(self.router.counts > 0):
-            return (*kernel_scan(), True)
-        if ready:
+        if self.backend == "kernel":
+            if ready and np.any(self.router.counts > 0):
+                self.passes["routed"] += 1
+                return (*kernel_scan(), True)
+        elif ready:
             info = self.router.route(q)
             if info is not None:
+                self.passes["routed"] += 1
                 return (*routed_scan(info), True)
+        self.passes["brute"] += 1
         self.last_scanned = len(self)
         self.last_scanned_max_shard = self.last_scanned
         return (*brute_scan(), False)
+
+    def _store_rows(self, ra: np.ndarray, embs: np.ndarray) -> None:
+        raise NotImplementedError
+
+    def add_batch(self, se_ids, embeddings) -> np.ndarray:
+        """Bulk add for large prefills (the million-entry sweeps): one
+        vectorized alloc+store per block instead of n scalar calls.
+
+        Stays on the scalar ``add`` path until the router trains —
+        the first refresh must trigger at the same index size as a
+        sequential loop would hit — then switches to bulk allocation +
+        ``note_add_batch`` (which itself splits at the router's exact
+        refresh points). Returns the allocated rows, ascending.
+        """
+        embs = np.asarray(embeddings, np.float32)
+        ids = np.asarray(se_ids, np.int64)
+        n = len(ids)
+        if len(self._free) < n:
+            raise RuntimeError("index full — evict first")
+        rows = np.empty(n, np.int64)
+        i = 0
+        while i < n and self.router is not None \
+                and not self.router.trained:
+            rows[i] = self.add(int(ids[i]), embs[i])
+            i += 1
+        rt = self.router
+        while i < n:
+            # allocate only up to the router's next refresh boundary: a
+            # refresh sees exactly the rows a sequential loop would have
+            # active (bulk-allocating ahead would leak not-yet-noted
+            # rows into the training sample and re-bucketing pass)
+            take = n - i
+            if rt is not None:
+                take = min(take, max(1, rt.cfg.refresh_every - rt._muts))
+            ra = np.array([self._free.pop() for _ in range(take)],
+                          np.int64)
+            self.active[ra] = True
+            self.row_se[ra] = ids[i:i + take]
+            self._store_rows(ra, embs[i:i + take])
+            rows[i:i + take] = ra
+            if rt is not None:
+                rt.note_add_batch(ra, embs[i:i + take], self)
+            i += take
+        return rows
 
     def remove_rows(self, rows) -> None:
         """Batched removal: one fancy-indexed store per field."""
@@ -219,12 +274,12 @@ class VectorIndex(RowIndex):
     the full-matrix brute force (DESIGN.md §12). Until the router has
     trained (or without one) the brute path runs unchanged."""
 
-    def __init__(self, capacity: int, dim: int, backend: str = "numpy",
-                 router=None):
+    def __init__(self, capacity: int, dim: int,
+                 backend: Optional[str] = None, router=None):
         super().__init__(capacity, dim, router=router)
-        self.backend = backend
+        self.backend = resolve_backend(backend)
         self.emb = np.zeros((capacity, dim), np.float32)
-        if backend == "kernel":
+        if self.backend == "kernel":
             from repro.kernels.ops import (
                 ann_topk_ivf_jit, ann_topk_ivf_sharded_jit, ann_topk_jit)
 
@@ -239,46 +294,8 @@ class VectorIndex(RowIndex):
             self.router.note_add(row, self.emb[row], self)
         return row
 
-    def add_batch(self, se_ids, embeddings) -> np.ndarray:
-        """Bulk add for large prefills (the million-entry sweeps): one
-        vectorized alloc+store per block instead of n scalar calls.
-
-        Stays on the scalar :meth:`add` path until the router trains —
-        the first refresh must trigger at the same index size as a
-        sequential loop would hit — then switches to bulk allocation +
-        ``note_add_batch`` (which itself splits at the router's exact
-        refresh points). Returns the allocated rows, ascending.
-        """
-        embs = np.asarray(embeddings, np.float32)
-        ids = np.asarray(se_ids, np.int64)
-        n = len(ids)
-        if len(self._free) < n:
-            raise RuntimeError("index full — evict first")
-        rows = np.empty(n, np.int64)
-        i = 0
-        while i < n and self.router is not None \
-                and not self.router.trained:
-            rows[i] = self.add(int(ids[i]), embs[i])
-            i += 1
-        rt = self.router
-        while i < n:
-            # allocate only up to the router's next refresh boundary: a
-            # refresh sees exactly the rows a sequential loop would have
-            # active (bulk-allocating ahead would leak not-yet-noted
-            # rows into the training sample and re-bucketing pass)
-            take = n - i
-            if rt is not None:
-                take = min(take, max(1, rt.cfg.refresh_every - rt._muts))
-            ra = np.array([self._free.pop() for _ in range(take)],
-                          np.int64)
-            self.active[ra] = True
-            self.row_se[ra] = ids[i:i + take]
-            self.emb[ra] = embs[i:i + take]
-            rows[i:i + take] = ra
-            if rt is not None:
-                rt.note_add_batch(ra, self.emb[ra], self)
-            i += take
-        return rows
+    def _store_rows(self, ra: np.ndarray, embs: np.ndarray) -> None:
+        self.emb[ra] = embs
 
     def _clear_rows(self, ra: np.ndarray) -> None:
         self.emb[ra] = 0.0
@@ -325,7 +342,7 @@ class VectorIndex(RowIndex):
         route()/gather happens at all — rows-scanned accounting derives
         from the kernel's own cluster selection."""
         rt = self.router
-        if rt.n_shards > 1 and self._ivf_sharded_fn is not None:
+        if rt.n_shards > 1:
             return self._search_routed_kernel_sharded(q, k)
         layout, bucket_rows, bucket_valid = rt.kernel_buckets(self)
         nprobe = rt.cfg.n_clusters if rt.cfg.nprobe is None \
@@ -367,9 +384,14 @@ class VectorIndex(RowIndex):
         return np.asarray(rows), np.asarray(sims)
 
     def _search_brute(self, q: np.ndarray, k: int):
-        if self._kernel_fn is not None:
+        if self.backend == "kernel":
             sims, rows = self._kernel_fn(self.emb, self.active, q, k)
             return np.asarray(rows), np.asarray(sims)
+        return self.numpy_brute(q, k)
+
+    def numpy_brute(self, q: np.ndarray, k: int):
+        """The numpy brute scan: the reference the Pallas kernel is
+        checked against, callable on either backend."""
         # (B, N) row-major so the per-query partition/sort runs over
         # contiguous lanes (axis=0 on (N, B) is strided and ~3× slower
         # at large N·B)

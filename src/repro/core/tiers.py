@@ -45,6 +45,7 @@ from repro.core.se_store import SEStore
 from repro.core.semantic_element import SemanticElement
 from repro.core.seri import (RowIndex, Seri, VectorIndex, sharded_topk_merge,
                              topk_desc, topk_desc_stable)
+from repro.kernels.platform import resolve_backend
 
 NEG = -3.0e38  # matches kernels/ann_topk_quant.NEG (masked-row sentinel)
 
@@ -88,20 +89,24 @@ class QuantIndex(RowIndex):
     the same order, so the coarse scores agree bit-for-bit.
     """
 
-    def __init__(self, capacity: int, dim: int, backend: str = "numpy",
-                 rescore_mult: int = 4, router=None):
+    def __init__(self, capacity: int, dim: int,
+                 backend: Optional[str] = None, rescore_mult: int = 4,
+                 router=None):
         super().__init__(capacity, dim, router=router)
-        self.backend = backend
+        self.backend = resolve_backend(backend)
         self.rescore_mult = rescore_mult
         self.emb_q = np.zeros((capacity, dim), np.int8)
-        # int32 mirror of emb_q for the numpy coarse matmul (numpy would
-        # otherwise overflow int8 accumulation — and per-search .astype
-        # copies of the whole matrix are the hot-path cost to avoid).
-        # On TPU the kernel reads the int8 matrix directly; the mirror is
-        # a host-simulation artifact.
-        self._emb_i32 = np.zeros((capacity, dim), np.int32)
+        # float mirror of emb_q for the numpy coarse matmul. Every
+        # partial sum of an int8·int8 dot is an integer of magnitude
+        # ≤ dim·127², so float32 BLAS gives the exact int32 dot while
+        # dim·127² < 2^24 (dim ≤ 1040) and float64 beyond — bit-equal to
+        # an integer matmul at BLAS speed, with no per-search .astype
+        # copy of the whole matrix. The kernel reads emb_q directly.
+        self._exact = np.float32 if dim * 127 * 127 < 2 ** 24 \
+            else np.float64
+        self._emb_exact = np.zeros((capacity, dim), self._exact)
         self.scale = np.zeros(capacity, np.float32)
-        if backend == "kernel":
+        if self.backend == "kernel":
             from repro.kernels.ops import (ann_topk_ivf_quant_jit,
                                            ann_topk_ivf_quant_sharded_jit,
                                            ann_topk_quant_jit)
@@ -114,7 +119,7 @@ class QuantIndex(RowIndex):
         row = self._alloc(se_id)
         q, s = quantize_rows(np.asarray(embedding, np.float32)[None])
         self.emb_q[row] = q[0]
-        self._emb_i32[row] = q[0]
+        self._emb_exact[row] = q[0]
         self.scale[row] = s[0]
         if self.router is not None:
             self.router.note_add(
@@ -122,9 +127,15 @@ class QuantIndex(RowIndex):
             )
         return row
 
+    def _store_rows(self, ra: np.ndarray, embs: np.ndarray) -> None:
+        q, s = quantize_rows(embs)
+        self.emb_q[ra] = q
+        self._emb_exact[ra] = q
+        self.scale[ra] = s
+
     def _clear_rows(self, ra: np.ndarray) -> None:
         self.emb_q[ra] = 0
-        self._emb_i32[ra] = 0
+        self._emb_exact[ra] = 0
         self.scale[ra] = 0.0
 
     def route_embs(self, rows: np.ndarray) -> np.ndarray:
@@ -154,7 +165,7 @@ class QuantIndex(RowIndex):
         to active rows (same values, same tie order)."""
         g_rows, allowed, self.last_scanned = routed
         rt = self.router
-        s = (qq.astype(np.int32) @ self._emb_i32[g_rows].T
+        s = (qq.astype(self._exact) @ self._emb_exact[g_rows].T
              ).astype(np.float32)
         s = s * self.scale[g_rows][None, :]
         s = s * qs[:, None]
@@ -179,7 +190,7 @@ class QuantIndex(RowIndex):
         route()/gather; rows-scanned derives from the kernel's own
         cluster selection."""
         rt = self.router
-        if rt.n_shards > 1 and self._ivf_sharded_fn is not None:
+        if rt.n_shards > 1:
             return self._coarse_routed_kernel_sharded(q, qq, qs, r)
         (bq, bscale), bucket_rows, bucket_valid = \
             rt.kernel_buckets(self, quant=True)
@@ -220,14 +231,19 @@ class QuantIndex(RowIndex):
         return np.asarray(rows), np.asarray(vals)
 
     def _coarse_brute(self, qq, qs, r: int):
-        if self._kernel_fn is not None:
+        if self.backend == "kernel":
             vals, rows = self._kernel_fn(
                 self.emb_q, self.scale, self.active, qq, qs, r
             )
             return np.asarray(rows), np.asarray(vals)
+        return self.numpy_coarse_brute(qq, qs, r)
+
+    def numpy_coarse_brute(self, qq, qs, r: int):
+        """The numpy brute coarse scan: the reference the Pallas kernel
+        is checked against, callable on either backend."""
         # (B, N) row-major, same layout rationale as VectorIndex;
         # scale multiply order matches the kernel exactly
-        s = (qq.astype(np.int32) @ self._emb_i32.T).astype(np.float32)
+        s = (qq.astype(self._exact) @ self._emb_exact.T).astype(np.float32)
         s = s * self.scale[None, :]
         s = s * qs[:, None]
         s = np.where(self.active[None, :], s, NEG)
@@ -355,9 +371,9 @@ class WarmTier:
     """
 
     def __init__(self, capacity_bytes: int, dim: int, *,
-                 index_capacity: int = 8192, backend: str = "numpy",
-                 value_ratio: float = 0.4, rescore_mult: int = 4,
-                 router=None):
+                 index_capacity: int = 8192,
+                 backend: Optional[str] = None, value_ratio: float = 0.4,
+                 rescore_mult: int = 4, router=None):
         # NOTE: the warm tier's extra access latency is an ENGINE-side
         # virtual-time cost (EngineConfig.t_cache_warm, like t_cache_cpu)
         # — it is deliberately not duplicated here
@@ -722,18 +738,19 @@ def make_tiered_cache(
     top_k: int = 4,
     eviction: str = "lcfu",
     max_ttl: float = 3600.0,
-    backend: str = "numpy",
+    backend: Optional[str] = None,
     warm_backend: Optional[str] = None,
     warm_value_ratio: float = 0.4,
     rescore_mult: int = 4,
     cluster: Optional[ClusterConfig] = None,
 ) -> TieredCache:
     """Factory mirroring ``make_cache``: hot fp32 index + seri in front of
-    an int8 warm tier. ``warm_backend`` defaults to the hot backend
-    ("kernel" → the quantized Pallas kernel). ``cluster`` enables the
-    clustered stage-1 routing (DESIGN.md §12) on BOTH tiers — each tier
-    gets its own router instance (the warm seed offset by 1 so the two
-    tiers' mini-batch draws are independent)."""
+    an int8 warm tier. ``backend=None`` lets the platform choose
+    (``kernels/platform.py``); ``warm_backend`` defaults to the hot
+    backend ("kernel" → the quantized Pallas kernel). ``cluster``
+    enables the clustered stage-1 routing (DESIGN.md §12) on BOTH tiers
+    — each tier gets its own router instance (the warm seed offset by 1
+    so the two tiers' mini-batch draws are independent)."""
     hot_router = warm_router = None
     if cluster is not None:
         wcap = warm_index_capacity or index_capacity

@@ -25,147 +25,128 @@ Two variants share the structure (mirroring ``ann_topk`` vs
     accumulate, approximate coarse scores for the WARM tier's
     coarse/rescore pipeline (the host rescores finalists in fp32).
 
-Per grid step: one (bucket_cap, D) slab · one query row on the MXU,
-invalid slots and disabled probes masked to NEG, per-step top-k via k
-max/argmax passes (the ``ann_topk`` idiom). The (nprobe · k) finalists
-per query merge in one ``lax.top_k`` outside the kernel. Disabled
-probes (query routed to fewer than ``nprobe`` non-empty clusters) emit
-NEG rows that callers drop via ``vals > NEG / 2``.
+Per grid step: one query row · one (bucket_cap, D) slab on the MXU,
+giving a (1, bucket_cap) score row; invalid slots and disabled probes
+are masked to NEG, and ``ann_topk.tile_topk`` reduces the row to the
+step's top-k. The (nprobe · k) finalists per query merge in one
+``lax.top_k`` outside the kernel. Disabled probes (query routed to fewer
+than ``nprobe`` non-empty clusters) emit NEG rows that callers drop via
+``vals > NEG / 2``.
+
+Per-query and per-bucket operands carry a unit middle axis ((B, 1, D)
+queries, (C, 1, cap) valid/scale rows, (B, nprobe, 1, k) outputs) so
+every block's last two dims equal the array's — the TPU block-shape
+rule — while the grid still picks one query and one bucket per step.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-NEG = -3.0e38  # plain float: jnp scalars would be captured consts in pallas
+from repro.kernels.ann_topk import _NT, NEG, contract_precision, tile_topk
+from repro.kernels.platform import resolve_interpret
+
+__all__ = ["NEG", "ann_topk_ivf", "ann_topk_ivf_quant"]
 
 
 def _ivf_kernel(sel_ref, en_ref, q_ref, bucket_ref, valid_ref, vals_ref,
                 idx_ref, *, k: int):
     """Grid step (b, j): scan bucket ``sel[b, j]`` for query b."""
-    b = pl.program_id(0)
-    j = pl.program_id(1)
+    en = en_ref[pl.program_id(0), pl.program_id(1)]
     bucket = bucket_ref[0]                   # (cap, D)
-    q = q_ref[...]                           # (1, D)
     s = jax.lax.dot_general(
-        bucket, q,
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )                                        # (cap, 1)
-    ok = (valid_ref[...] > 0)[0] & (en_ref[b, j] > 0)
-    s = jnp.where(ok[:, None], s, NEG)
-    rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-    for t in range(k):
-        v = jnp.max(s, axis=0)               # (1,)
-        i = jnp.argmax(s, axis=0)            # (1,) slot within bucket
-        vals_ref[0, 0, t] = v[0]
-        idx_ref[0, 0, t] = i.astype(jnp.int32)[0]
-        s = jnp.where(rows == i[None, :], NEG, s)
+        q_ref[0], bucket, _NT, preferred_element_type=jnp.float32,
+        precision=contract_precision(bucket.dtype),
+    )                                        # (1, cap)
+    s = jnp.where(valid_ref[0] * en > 0, s, NEG)
+    vals_ref[0, 0], idx_ref[0, 0] = tile_topk(s, k)
 
 
 def _ivf_quant_kernel(sel_ref, en_ref, qq_ref, qs_ref, bucket_ref,
                       scale_ref, valid_ref, vals_ref, idx_ref, *, k: int):
     """int8 variant: int32-exact scores rescaled like ann_topk_quant
     (row scale first, then query scale — bit-matching the numpy path)."""
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-    bucket = bucket_ref[0]                   # (cap, D) int8
-    qq = qq_ref[...]                         # (1, D) int8
+    en = en_ref[pl.program_id(0), pl.program_id(1)]
     s = jax.lax.dot_general(
-        bucket, qq,
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.int32,
-    )                                        # (cap, 1) exact int32
-    s = s.astype(jnp.float32) * scale_ref[...][0][:, None]
-    s = s * qs_ref[b]
-    ok = (valid_ref[...] > 0)[0] & (en_ref[b, j] > 0)
-    s = jnp.where(ok[:, None], s, NEG)
-    rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-    for t in range(k):
-        v = jnp.max(s, axis=0)
-        i = jnp.argmax(s, axis=0)
-        vals_ref[0, 0, t] = v[0]
-        idx_ref[0, 0, t] = i.astype(jnp.int32)[0]
-        s = jnp.where(rows == i[None, :], NEG, s)
+        qq_ref[0], bucket_ref[0], _NT, preferred_element_type=jnp.int32,
+    )                                        # (1, cap) exact int32
+    s = s.astype(jnp.float32) * scale_ref[0]
+    s = s * qs_ref[0]                        # (1, 1) query scale
+    s = jnp.where(valid_ref[0] * en > 0, s, NEG)
+    vals_ref[0, 0], idx_ref[0, 0] = tile_topk(s, k)
+
+
+def _query_spec(width: int):
+    """Query b's (1, width) row of a (B, 1, width) operand."""
+    return pl.BlockSpec((1, 1, width), lambda bi, j, sel, en: (bi, 0, 0))
+
+
+def _routed_call(kernel, sel, enabled, operands, in_specs, k: int,
+                 interpret):
+    """The (B, nprobe) scalar-prefetch grid both routed scans share."""
+    b, nprobe = sel.shape
+    vals, slots = pl.pallas_call(
+        functools.partial(kernel, k=k),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,           # sel, enabled
+            grid=(b, nprobe),
+            in_specs=in_specs,
+            out_specs=[pl.BlockSpec((1, 1, 1, k),
+                                    lambda bi, j, sel, en: (bi, j, 0, 0))] * 2,
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((b, nprobe, 1, k), jnp.float32),
+            jax.ShapeDtypeStruct((b, nprobe, 1, k), jnp.int32),
+        ],
+        interpret=resolve_interpret(interpret),
+    )(sel, enabled, *operands)
+    return vals[:, :, 0], slots[:, :, 0]
+
+
+def _bucket_specs(cap: int, d: int, n_rows: int):
+    """The selected bucket's (cap, D) slab, then ``n_rows`` (1, cap)
+    per-slot rows (valid mask, scales) of the same bucket."""
+    def bucket(bi, j, sel, en):
+        return (sel[bi, j], 0, 0)
+
+    return [pl.BlockSpec((1, cap, d), bucket)] + \
+        [pl.BlockSpec((1, 1, cap), bucket)] * n_rows
 
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
 def ann_topk_ivf(sel, enabled, q, buckets, bucket_valid, k: int = 4, *,
-                 interpret: bool = True):
+                 interpret: Optional[bool] = None):
     """Routed fp32 scan. sel/enabled (B, nprobe) int32; q (B, D);
     buckets (C, cap, D); bucket_valid (C, cap) -> per-probe finalists
-    (vals (B, nprobe, k), slots (B, nprobe, k)).
-
-    interpret=True executes the kernel body on CPU (this container);
-    on TPU pass interpret=False for the Mosaic lowering.
-    """
-    b, nprobe = sel.shape
-    _, cap, d = buckets.shape
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,               # sel, enabled
-        grid=(b, nprobe),
-        in_specs=[
-            pl.BlockSpec((1, d), lambda bi, j, sel, en: (bi, 0)),
-            pl.BlockSpec((1, cap, d),
-                         lambda bi, j, sel, en: (sel[bi, j], 0, 0)),
-            pl.BlockSpec((1, cap),
-                         lambda bi, j, sel, en: (sel[bi, j], 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, k), lambda bi, j, sel, en: (bi, j, 0)),
-            pl.BlockSpec((1, 1, k), lambda bi, j, sel, en: (bi, j, 0)),
-        ],
+    (vals (B, nprobe, k), slots (B, nprobe, k)). ``interpret=None``
+    compiles on TPU and interprets on CPU."""
+    c, cap, d = buckets.shape
+    return _routed_call(
+        _ivf_kernel, sel, enabled,
+        (q[:, None, :], buckets, bucket_valid.reshape(c, 1, cap)),
+        [_query_spec(d)] + _bucket_specs(cap, d, 1), k, interpret,
     )
-    return pl.pallas_call(
-        functools.partial(_ivf_kernel, k=k),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((b, nprobe, k), jnp.float32),
-            jax.ShapeDtypeStruct((b, nprobe, k), jnp.int32),
-        ],
-        interpret=interpret,
-    )(sel, enabled, q, buckets, bucket_valid)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
 def ann_topk_ivf_quant(sel, enabled, qq, q_scales, buckets_q, bucket_scale,
                        bucket_valid, k: int = 16, *,
-                       interpret: bool = True):
+                       interpret: Optional[bool] = None):
     """Routed int8 coarse scan. qq (B, D) int8; q_scales (B,) f32;
     buckets_q (C, cap, D) int8; bucket_scale (C, cap) f32 -> per-probe
     coarse finalists (vals, slots) as in :func:`ann_topk_ivf`. ``vals``
     are approximate — callers rescore in fp32 before the τ_sim gate.
     """
-    b, nprobe = sel.shape
-    _, cap, d = buckets_q.shape
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, nprobe),
-        in_specs=[
-            pl.BlockSpec((1, d), lambda bi, j, sel, en: (bi, 0)),
-            pl.BlockSpec((b,), lambda bi, j, sel, en: (0,)),
-            pl.BlockSpec((1, cap, d),
-                         lambda bi, j, sel, en: (sel[bi, j], 0, 0)),
-            pl.BlockSpec((1, cap),
-                         lambda bi, j, sel, en: (sel[bi, j], 0)),
-            pl.BlockSpec((1, cap),
-                         lambda bi, j, sel, en: (sel[bi, j], 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, k), lambda bi, j, sel, en: (bi, j, 0)),
-            pl.BlockSpec((1, 1, k), lambda bi, j, sel, en: (bi, j, 0)),
-        ],
+    c, cap, d = buckets_q.shape
+    return _routed_call(
+        _ivf_quant_kernel, sel, enabled,
+        (qq[:, None, :], q_scales[:, None, None], buckets_q,
+         bucket_scale.reshape(c, 1, cap), bucket_valid.reshape(c, 1, cap)),
+        [_query_spec(d), _query_spec(1)] + _bucket_specs(cap, d, 2), k,
+        interpret,
     )
-    return pl.pallas_call(
-        functools.partial(_ivf_quant_kernel, k=k),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((b, nprobe, k), jnp.float32),
-            jax.ShapeDtypeStruct((b, nprobe, k), jnp.int32),
-        ],
-        interpret=interpret,
-    )(sel, enabled, qq, q_scales, buckets_q, bucket_scale, bucket_valid)

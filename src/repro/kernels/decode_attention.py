@@ -10,11 +10,14 @@ has already written the new token's K/V at index pos).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.platform import resolve_interpret
 
 NEG = -1.0e30
 
@@ -61,8 +64,9 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
     jax.jit, static_argnames=("scale", "bs", "interpret")
 )
 def decode_attention(q, k_cache, v_cache, pos, *, scale: float,
-                     bs: int = 512, interpret: bool = True):
-    """q (B,KV,G,Dh); caches (B,S,KV,Dh); pos scalar -> (B,KV,G,Dh)."""
+                     bs: int = 512, interpret: Optional[bool] = None):
+    """q (B,KV,G,Dh); caches (B,S,KV,Dh); pos scalar -> (B,KV,G,Dh).
+    ``interpret=None`` compiles on TPU and interprets on CPU."""
     b, kvh, g, dh = q.shape
     s_cache = k_cache.shape[1]
     bs = min(bs, s_cache)
@@ -92,6 +96,6 @@ def decode_attention(q, k_cache, v_cache, pos, *, scale: float,
             pltpu.VMEM((g,), jnp.float32),
             pltpu.VMEM((g, dh), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(pos_arr, qf, kf, vf)
     return out.reshape(b, kvh, g, dh)

@@ -15,11 +15,14 @@ uses nn.flash (same math, custom_vjp).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.platform import resolve_interpret
 
 NEG = -1.0e30
 
@@ -75,8 +78,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 )
 def flash_attention_fwd(q, k, v, *, scale: float, causal: bool = True,
                         window=None, bq: int = 512, bk: int = 512,
-                        interpret: bool = True):
-    """q (B,Sq,KV,G,Dh); k/v (B,Sk,KV,Dh) -> (B,Sq,KV,G,Dh)."""
+                        interpret: Optional[bool] = None):
+    """q (B,Sq,KV,G,Dh); k/v (B,Sk,KV,Dh) -> (B,Sq,KV,G,Dh).
+    ``interpret=None`` compiles on TPU and interprets on CPU."""
     b, sq, kvh, g, dh = q.shape
     sk = k.shape[1]
     bq = min(bq, sq)
@@ -115,7 +119,7 @@ def flash_attention_fwd(q, k, v, *, scale: float, causal: bool = True,
             pltpu.VMEM((bq,), jnp.float32),     # running denom l
             pltpu.VMEM((bq, dh), jnp.float32),  # output accumulator
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qf, kf, vf)
     out = out.reshape(b, kvh, g, sq, dh)
     return jnp.moveaxis(out, 3, 1)

@@ -1,7 +1,7 @@
 """jit'd public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True because this container is CPU-only; on a
-real TPU the launchers pass interpret=False for the Mosaic lowering. The
+Every kernel compiles on TPU and runs interpreted on CPU; the platform
+decides (``kernels/platform.py``), so no wrapper takes a mode. The
 pure-jnp oracles live in kernels.ref; tests sweep shapes/dtypes and
 assert_allclose kernel-vs-ref.
 """
@@ -58,9 +58,13 @@ def _route(centroids, live, q, nprobe: int):
     """Centroid scoring + top-``nprobe`` cluster selection — the routing
     half of the fused IVF scan, in the same jit scope as the
     ``pallas_call`` (it cannot live inside it: the scan grid's
-    scalar-prefetch index maps need ``sel`` before the first step)."""
-    cs = jnp.where(jnp.asarray(live) > 0,
-                   jnp.asarray(q) @ jnp.asarray(centroids).T, NEG)
+    scalar-prefetch index maps need ``sel`` before the first step).
+    Full fp32 precision: at the TPU's default single bf16 pass the
+    selected clusters differ from the numpy router's, which changes
+    hits, not just scores."""
+    scores = jnp.matmul(jnp.asarray(q), jnp.asarray(centroids).T,
+                        precision=jax.lax.Precision.HIGHEST)
+    cs = jnp.where(jnp.asarray(live) > 0, scores, NEG)
     svals, sel = jax.lax.top_k(cs, nprobe)
     return sel.astype(jnp.int32), (svals > NEG / 2).astype(jnp.int32)
 

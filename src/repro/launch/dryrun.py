@@ -268,8 +268,6 @@ def run_cell(arch: str, shape: str, multi_pod: bool, verbose: bool = True,
 
     ma = compiled.memory_analysis()
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # jax < 0.5 returns [dict]
-        ca = ca[0] if ca else {}
     hlo = compiled.as_text()
     colls = parse_collectives(hlo, n_dev)
 

@@ -9,11 +9,7 @@ cross-link) dimension and defaults to pure data parallelism.
 from __future__ import annotations
 
 import jax
-
-try:
-    from jax.sharding import AxisType
-except ImportError:  # jax < 0.5: explicit axis types don't exist yet
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def make_shard_mesh(n_shards: int):
@@ -37,8 +33,6 @@ def make_shard_mesh(n_shards: int):
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(
         shape, axes, axis_types=(AxisType.Auto,) * len(axes)
     )

@@ -18,6 +18,7 @@ from repro.data.workloads import (churn_workload, longtail_workload,
                                   swe_workload, trend_workload,
                                   zipf_workload)
 from repro.data.world import MutableWorld, SemanticWorld
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving.clock import VirtualClock
 from repro.serving.engine import Engine, EngineConfig, ExactCache
 from repro.serving.gpu import GPU, GPUConfig
@@ -64,6 +65,9 @@ def run_once(
     judge_adaptive_band: bool = False,
     judge_compute: str = "oracle",
     judge_d_model: int = 128,
+    judge_config: str | None = None,
+    judge_base_tokens: float | None = None,
+    judge_model=None,
     judge_max_len: int = 128,
     recalibrate_every: float | None = None,
     prefetch: bool = True,
@@ -97,8 +101,20 @@ def run_once(
     stale_age_reservoir: int | None = None,
     faults: list | None = None,
     overload: str | None = None,
+    backend: str | None = None,
+    on_done=None,
     seed: int = 0,
 ) -> dict:
+    """One solo-engine run; returns the engine's summary dict.
+
+    ``judge_config`` names a registered config (e.g. ``"qwen3-0.6b"``)
+    that the judge runs unshrunk; otherwise the judge is the
+    ``judge_d_model`` shrink. ``judge_base_tokens`` pins the virtual-time
+    price of one judge job (default: derived from the judge config's
+    prefill FLOPs). ``judge_model`` reuses a built ``ModelJudge`` (and
+    its compiled prefill) for ``judge_compute="model"``. ``backend``
+    picks the stage-1 index backend (None: the platform's).
+    ``on_done(engine)`` runs after the engine finishes."""
     # churn_period switches the ground truth to a MutableWorld whose
     # low-staticity intents update every churn_period seconds (DESIGN.md
     # §11); None keeps the immutable world, and stale_hits stays 0.
@@ -121,15 +137,22 @@ def run_once(
                                                default_judge_cfg)
 
         oracle = OracleJudge(world, accuracy=judge_acc, seed=seed + 2)
-        jcfg = default_judge_cfg(d_model=judge_d_model)
+        if judge_model is not None:
+            jcfg = judge_model.cfg
+        elif judge_config is not None:
+            from repro.configs import get_config
+
+            jcfg = get_config(judge_config)
+        else:
+            jcfg = default_judge_cfg(d_model=judge_d_model)
         model = None
         if judge_compute == "model":
-            # pay real tiny-LM prefill per judge micro-batch (the
-            # calibration shim: oracle decisions, model compute)
+            # pay real prefill per judge micro-batch (the calibration
+            # shim: oracle decisions, model compute)
             from repro.core.judge import ModelJudge
 
-            model = ModelJudge(cfg=jcfg, max_len=judge_max_len,
-                               seed=seed + 6)
+            model = judge_model or ModelJudge(
+                cfg=jcfg, max_len=judge_max_len, seed=seed + 6)
         band = None
         if judge_band is not None:
             band = AdmissionBand(width=judge_band,
@@ -138,7 +161,8 @@ def run_once(
         # derived token cost + optional real compute. judge_band=None
         # (and oracle compute) is today's engine, event for event.
         judge = JudgePipeline(oracle, compute=model, judge_cfg=jcfg,
-                              max_len=judge_max_len, band=band)
+                              max_len=judge_max_len, band=band,
+                              base_tokens=judge_base_tokens)
         # clustered (IVF) stage-1 routing, DESIGN.md §12; nprobe=None
         # probes every cluster (the brute-force-parity mode). shards>1
         # (the §13 mesh partition) requires the router, so it implies
@@ -157,11 +181,12 @@ def run_once(
                 hot_bytes=cap - warm_bytes, warm_bytes=warm_bytes,
                 dim=dim, judge=judge, eviction=eviction, max_ttl=max_ttl,
                 warm_value_ratio=warm_value_ratio, cluster=ccfg,
+                backend=backend,
             )
         else:
             cache = make_cache(
                 capacity_bytes=cap, dim=dim, judge=judge, eviction=eviction,
-                max_ttl=max_ttl, cluster=ccfg,
+                max_ttl=max_ttl, cluster=ccfg, backend=backend,
             )
     elif mode == "exact":
         exact = ExactCache(cap, max_ttl=max_ttl)
@@ -258,6 +283,8 @@ def run_once(
                                     monitor=monitor)
         sampler.start()
     out = eng.run()
+    if on_done is not None:
+        on_done(eng)
     if sampler is not None:
         sampler.finalize()
         # telemetry-enabled runs get extra keys ONLY — with
@@ -396,11 +423,19 @@ def main(argv=None):
                          "(needs --recalibrate-every)")
     ap.add_argument("--judge-compute", default="oracle",
                     choices=["oracle", "model"],
-                    help="'model' pays real tiny-LM prefill per judge "
+                    help="'model' pays real judge prefill per "
                          "micro-batch (decisions stay oracle-faithful)")
     ap.add_argument("--judge-d-model", type=int, default=128,
                     help="judge model width; sets the FLOPs-derived "
                          "judge token cost (16.0 token-eq at 128)")
+    ap.add_argument("--judge-config", default=None, metavar="NAME",
+                    help="run the judge as this registered config, "
+                         "unshrunk (e.g. qwen3-0.6b); overrides "
+                         "--judge-d-model")
+    ap.add_argument("--judge-base-tokens", type=float, default=None,
+                    help="pin the virtual-time price of one judge job in "
+                         "token-eq (default: from the judge's prefill "
+                         "FLOPs)")
     ap.add_argument("--judge-max-len", type=int, default=128,
                     help="judge prefill length in tokens")
     ap.add_argument("--no-prefetch", action="store_true")
@@ -461,6 +496,7 @@ def main(argv=None):
                     help="federation topology for --regions > 1")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.regions > 1:
         s = run_federated(
@@ -492,6 +528,8 @@ def main(argv=None):
         judge_adaptive_band=args.judge_adaptive_band,
         judge_compute=args.judge_compute,
         judge_d_model=args.judge_d_model,
+        judge_config=args.judge_config,
+        judge_base_tokens=args.judge_base_tokens,
         judge_max_len=args.judge_max_len,
         recalibrate_every=args.recalibrate_every,
         prefetch=not args.no_prefetch,

@@ -25,7 +25,7 @@ from repro.nn import attention as att
 from repro.nn import basic, moe as moe_mod, ssm, xlstm as xl
 from repro.nn.config import LayerSpec, ModelConfig
 from repro.nn.param import ParamSpec, stack_tree
-from repro.nn.sharding import ShardCtx, shard_map_compat
+from repro.nn.sharding import ShardCtx
 
 from repro.nn import runtime as _runtime
 
@@ -606,7 +606,7 @@ def _sharded_embed(ctx: ShardCtx, table, tokens):
         out = jnp.take(tbl, loc, axis=0) * ok[..., None].astype(tbl.dtype)
         return jax.lax.psum(out, axis)
 
-    out = shard_map_compat(
+    out = jax.shard_map(
         inner, mesh=ctx.mesh,
         in_specs=(P(axis, None), P(dp, None)),
         out_specs=P(dp, None, None),
@@ -649,7 +649,7 @@ def _sharded_xent(ctx: ShardCtx, logits, labels):
             total = jax.lax.psum(total, dp)
         return total / n_tokens
 
-    return shard_map_compat(
+    return jax.shard_map(
         inner, mesh=ctx.mesh,
         in_specs=(P(dp, None, axis), P(dp, None)),
         out_specs=P(),

@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from repro.nn.basic import ffn, ffn_specs
 from repro.nn.config import MoEConfig
 from repro.nn.param import ParamSpec
-from repro.nn.sharding import ShardCtx, shard_map_compat
+from repro.nn.sharding import ShardCtx
 
 
 def moe_specs(cfg: MoEConfig, d_model: int, dtype) -> dict:
@@ -195,7 +195,7 @@ def _moe_shardmap(ctx, pw, cfg, xg, w, slot_src, tok_slot, cap, e_local):
         )
         return jax.lax.psum(out, axis)
 
-    return shard_map_compat(
+    return jax.shard_map(
         inner,
         mesh=mesh,
         in_specs=(

@@ -17,15 +17,11 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.nn.sharding import shard_map_compat
-
 
 def _pcast_varying(x, axis: str):
-    """`jax.lax.pcast` annotates device-varying values for the new (jax ≥
-    0.5) shard_map rep checker; on older jax (check_rep=False fallback in
-    shard_map_compat) it doesn't exist and isn't needed."""
-    pcast = getattr(jax.lax, "pcast", None)
-    return x if pcast is None else pcast(x, (axis,), to="varying")
+    """Mark a value as device-varying along ``axis`` for shard_map's
+    varying-axes checker."""
+    return jax.lax.pcast(x, (axis,), to="varying")
 
 
 def pipeline_apply(mesh, axis: str, stage_fn, stage_params, x_microbatches):
@@ -77,7 +73,7 @@ def pipeline_apply(mesh, axis: str, stage_fn, stage_params, x_microbatches):
         # only the last stage collected outputs; psum replicates them
         return jax.lax.psum(outs, axis)
 
-    return shard_map_compat(
+    return jax.shard_map(
         inner, mesh=mesh, in_specs=(P(axis), P()), out_specs=P(),
         axis_names={axis},
     )(stage_params, x_microbatches)

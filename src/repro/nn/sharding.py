@@ -86,21 +86,6 @@ class ShardingConfig:
         return self.rules.get(logical, ())
 
 
-def shard_map_compat(fn, *, mesh, in_specs, out_specs, axis_names):
-    """`jax.shard_map` when available (jax ≥ 0.5), else the experimental
-    shard_map with replication checking off — `axis_names` only exists in
-    the new API and the old rep checker rejects these fully-manual
-    kernels anyway."""
-    new = getattr(jax, "shard_map", None)
-    if new is not None:
-        return new(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   axis_names=axis_names)
-    from jax.experimental.shard_map import shard_map as old
-
-    return old(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
 def axis_size(mesh: Mesh, names: tuple[str, ...]) -> int:
     size = 1
     for n in names:

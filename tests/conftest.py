@@ -1,50 +1,5 @@
-import sys
-import types
-
 import numpy as np
 import pytest
-
-# ---------------------------------------------------------------- shim
-# `hypothesis` is a dev-only dependency (requirements-dev.txt). When it is
-# absent, install a stub so the property-test modules still *collect*: the
-# @given tests turn into explicit skips and every non-hypothesis test in
-# those modules keeps running.
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    class _AnyStrategy:
-        """Stands in for any strategy object/combinator at collect time."""
-
-        def __call__(self, *args, **kwargs):
-            return self
-
-        def __getattr__(self, name):
-            return self
-
-    _ANY = _AnyStrategy()
-
-    def _given(*_args, **_kwargs):
-        def deco(fn):
-            def skipper():
-                pytest.skip("hypothesis not installed")
-
-            skipper.__name__ = getattr(fn, "__name__", "hypothesis_test")
-            skipper.__doc__ = fn.__doc__
-            return skipper
-
-        return deco
-
-    def _settings(*_args, **_kwargs):
-        return lambda fn: fn
-
-    _hyp = types.ModuleType("hypothesis")
-    _st = types.ModuleType("hypothesis.strategies")
-    _hyp.given = _given
-    _hyp.settings = _settings
-    _hyp.strategies = _st
-    _st.__getattr__ = lambda name: _ANY
-    sys.modules["hypothesis"] = _hyp
-    sys.modules["hypothesis.strategies"] = _st
 
 
 @pytest.fixture(scope="session")

@@ -1,5 +1,5 @@
-"""Per-kernel shape/dtype sweeps vs the pure-jnp oracles (interpret=True
-executes the Pallas kernel bodies on CPU)."""
+"""Per-kernel shape/dtype sweeps vs the pure-jnp oracles (on CPU the
+platform runs the Pallas kernel bodies in interpret mode)."""
 import jax.numpy as jnp
 import numpy as np
 import pytest

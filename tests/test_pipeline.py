@@ -12,12 +12,9 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from repro.nn.pipeline import pipeline_apply
 
-try:
-    from jax.sharding import AxisType
-    mesh = jax.make_mesh((4, 2), ("pod", "data"),
-                         axis_types=(AxisType.Auto,) * 2)
-except ImportError:  # jax < 0.5: explicit axis types don't exist yet
-    mesh = jax.make_mesh((4, 2), ("pod", "data"))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((4, 2), ("pod", "data"),
+                     axis_types=(AxisType.Auto,) * 2)
 S, M, mb, d = 4, 6, 2, 8
 params = jnp.arange(1.0, S + 1)[:, None] * jnp.ones((S, d))
 x = jnp.asarray(np.random.default_rng(0).standard_normal((M, mb, d)),
