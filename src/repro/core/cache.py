@@ -29,6 +29,7 @@ from repro.core.se_store import SEStore, SEStoreMapping
 from repro.core.semantic_element import SemanticElement, ttl_from_staticity
 from repro.core.seri import Seri, SeriResult, VectorIndex
 from repro.obs.metrics import ScanMetrics
+from repro.obs.trace import NULL_TRACER
 
 
 @dataclasses.dataclass
@@ -83,6 +84,20 @@ class CortexCache:
         # re-enters HOT, so the FreshnessManager can re-arm its
         # refresh-ahead timer (the timer dies while an entry sits warm)
         self.on_promote = None
+        # wall spans of the served path (DESIGN.md §15); bind_tracer arms
+        self.tracer = NULL_TRACER
+
+    def bind_tracer(self, tracer) -> None:
+        """Draw wall-clock spans of the served path into ``tracer``:
+        ``cache.lookup_batch`` / ``cache.insert`` here, the index's
+        ``seri.*`` and the judge model's ``judge.*`` (the pipeline's
+        ``compute``, when it has ``bind_tracer``). Tracing is on exactly
+        while an enabled tracer is bound."""
+        self.tracer = tracer
+        self.seri.index.tracer = tracer
+        bind = getattr(self.seri.pipeline.compute, "bind_tracer", None)
+        if bind is not None:
+            bind(tracer)
 
     @property
     def rows(self) -> dict[int, int]:
@@ -148,8 +163,9 @@ class CortexCache:
         found = self.seri.index.search_batch(
             np.asarray(q_embs), self.seri.top_k, self.seri.stage1_gate
         )
-        self.scan.note_pass(self.seri.index.last_scanned,
-                            self.seri.index.last_scanned_max_shard)
+        ix = self.seri.index
+        self.scan.note_pass(ix.last_scanned, ix.last_scanned_max_shard,
+                            ix.last_h2d_bytes)
         out = []
         for se_ids, sims in found:
             # revalidating rows are KNOWN stale (change-feed notice,
@@ -217,9 +233,10 @@ class CortexCache:
         matmul / ``ann_topk`` launch, stage 2 in one judge call. Hit
         bookkeeping is applied in query order, so the hit/miss sequence is
         identical to sequential scalar lookups from the same state."""
-        self.stats.lookups += len(queries)
-        blocks, _ = self._stage1_blocks(q_embs, now)
-        return self._judge_blocks(queries, blocks, now)
+        with self.tracer.wall("cache.lookup_batch"):
+            self.stats.lookups += len(queries)
+            blocks, _ = self._stage1_blocks(q_embs, now)
+            return self._judge_blocks(queries, blocks, now)
 
     # ---------------------------------------------------- staged lookup
     # The serving engine needs the two Seri stages split so the judge can
@@ -305,47 +322,52 @@ class CortexCache:
         version: int = 0,
         fetched_at: Optional[float] = None,
     ) -> SemanticElement:
-        # `is None`, not truthiness: staticity 0 is a legitimate caller
-        # override and must not trigger a judge re-estimate
-        if staticity is None:
-            staticity = self.seri.pipeline.staticity(query)
-        if ttl is None:
-            # explicit ttl: federated transfers admit with the SOURCE
-            # entry's remaining lifetime so a copy never outlives its origin
-            ttl = ttl_from_staticity(staticity, self.max_ttl, self.min_ttl)
-        self._make_room(size, now)
-        if self.seri.index.full:
-            self._evict_n(1, now)
-        se_id = self._next_id
-        self._next_id += 1
-        row = self.seri.index.add(se_id, q_emb)
-        se = self.soa.add(
-            row, se_id,
-            key=query,
-            value=value,
-            staticity=staticity,
-            cost=cost,
-            latency=latency,
-            size=size,
-            created_at=now,
-            expires_at=now + ttl,
-            # the triggering miss counts as an access; only speculative
-            # prefetches enter cold (paper §4.3: "prefetched items enter
-            # with zero frequency")
-            freq=0 if prefetched else 1,
-            last_access=now,
-            prefetched=prefetched,
-            intent=intent,
-            origin=origin,
-            version=version,
-            fetched_at=fetched_at,
-        )
-        self.usage += size
-        self.stats.insertions += 1
-        if prefetched:
-            self.stats.prefetch_inserts += 1
-        self.stats.bytes_stored = self.usage
-        return se
+        with self.tracer.wall("cache.insert"):
+            # `is None`, not truthiness: staticity 0 is a legitimate
+            # caller override and must not trigger a judge re-estimate
+            if staticity is None:
+                staticity = self.seri.pipeline.staticity(query)
+            if ttl is None:
+                # explicit ttl: federated transfers admit with the SOURCE
+                # entry's remaining lifetime so a copy never outlives its
+                # origin
+                ttl = ttl_from_staticity(staticity, self.max_ttl,
+                                         self.min_ttl)
+            with self.tracer.wall("cache.evict"):
+                self._make_room(size, now)
+                if self.seri.index.full:
+                    self._evict_n(1, now)
+            se_id = self._next_id
+            self._next_id += 1
+            with self.tracer.wall("seri.add"):
+                row = self.seri.index.add(se_id, q_emb)
+            se = self.soa.add(
+                row, se_id,
+                key=query,
+                value=value,
+                staticity=staticity,
+                cost=cost,
+                latency=latency,
+                size=size,
+                created_at=now,
+                expires_at=now + ttl,
+                # the triggering miss counts as an access; only
+                # speculative prefetches enter cold (paper §4.3:
+                # "prefetched items enter with zero frequency")
+                freq=0 if prefetched else 1,
+                last_access=now,
+                prefetched=prefetched,
+                intent=intent,
+                origin=origin,
+                version=version,
+                fetched_at=fetched_at,
+            )
+            self.usage += size
+            self.stats.insertions += 1
+            if prefetched:
+                self.stats.prefetch_inserts += 1
+            self.stats.bytes_stored = self.usage
+            return se
 
     def insert_batch(self, items: Sequence[dict], *,
                      now: float) -> list[SemanticElement]:

@@ -22,6 +22,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.obs.trace import NULL_TRACER
+
 
 def l2_normalize(x: np.ndarray, axis: int = -1) -> np.ndarray:
     n = np.linalg.norm(x, axis=axis, keepdims=True)
@@ -63,6 +65,12 @@ class ModelEmbedder:
             )
 
         self._encode = jax.jit(encode)
+        self.tracer = NULL_TRACER
+
+    def bind_tracer(self, tracer) -> None:
+        """Draw ``embedder.*`` wall spans into ``tracer`` (DESIGN.md
+        §15)."""
+        self.tracer = tracer
 
     @property
     def dim(self) -> int:
@@ -74,9 +82,14 @@ class ModelEmbedder:
             % self.cfg.vocab_size
 
     def embed_batch(self, texts: Sequence[str]) -> np.ndarray:
-        return np.asarray(
-            self._encode(self.params, jnp.asarray(self.tokens(texts))),
-            np.float32)
+        tr = self.tracer
+        with tr.wall("embedder.embed_batch"):
+            with tr.wall("embedder.tokenize"):
+                toks = self.tokens(texts)
+            with tr.wall("embedder.encode"):
+                return np.asarray(
+                    self._encode(self.params, jnp.asarray(toks)),
+                    np.float32)
 
 
 class WorldEmbedder:

@@ -22,6 +22,8 @@ from typing import Optional, Sequence
 
 import numpy as np
 
+from repro.obs.trace import NULL_TRACER
+
 
 @dataclasses.dataclass
 class JudgeVerdict:
@@ -113,7 +115,8 @@ class ModelJudge:
     widths, or a shrink of it. ``score_pairs`` pads the pair count up to
     the next of :data:`PAIR_BUCKETS` (longer lists go in chunks of the
     largest), so the jitted prefill compiles at most once per bucket;
-    ``shapes_compiled`` records the buckets used."""
+    ``shapes_compiled`` records the buckets used, ``pairs_scored`` the
+    real pairs and ``pairs_padded`` the pad pairs the buckets added."""
 
     def __init__(self, cfg=None, max_len: int = 128, seed: int = 1):
         import jax
@@ -145,24 +148,42 @@ class ModelJudge:
 
         self._score = jax.jit(score)
         self.shapes_compiled: set[int] = set()
+        self.pairs_scored = 0
+        self.pairs_padded = 0
+        self.tracer = NULL_TRACER
+
+    def bind_tracer(self, tracer) -> None:
+        """Draw ``judge.*`` wall spans into ``tracer`` (DESIGN.md §15)."""
+        self.tracer = tracer
 
     def score_pairs(self, queries, cached_keys) -> np.ndarray:
-        toks = np.array([
-            self._byte_tokens(f"{q} [SEP] {c}", self.max_len)
-            for q, c in zip(queries, cached_keys)
-        ], np.int32).reshape(-1, self.max_len) % self.cfg.vocab_size
-        out = []
-        top = PAIR_BUCKETS[-1]
-        for off in range(0, len(toks), top):
-            chunk = toks[off:off + top]
-            m = len(chunk)
-            size = next(b for b in PAIR_BUCKETS if b >= m)
-            self.shapes_compiled.add(size)
-            padded = np.zeros((size, self.max_len), np.int32)
-            padded[:m] = chunk
-            out.append(np.asarray(self._score(self.params, padded))[:m])
-        return np.concatenate(out).astype(np.float32) if out \
-            else np.zeros(0, np.float32)
+        tr = self.tracer
+        with tr.wall("judge.score_pairs"):
+            with tr.wall("judge.tokenize"):
+                toks = np.array([
+                    self._byte_tokens(f"{q} [SEP] {c}", self.max_len)
+                    for q, c in zip(queries, cached_keys)
+                ], np.int32).reshape(-1, self.max_len) \
+                    % self.cfg.vocab_size
+                chunks = []
+                top = PAIR_BUCKETS[-1]
+                for off in range(0, len(toks), top):
+                    chunk = toks[off:off + top]
+                    m = len(chunk)
+                    size = next(b for b in PAIR_BUCKETS if b >= m)
+                    self.shapes_compiled.add(size)
+                    padded = np.zeros((size, self.max_len), np.int32)
+                    padded[:m] = chunk
+                    chunks.append((m, padded))
+                    self.pairs_scored += m
+                    self.pairs_padded += size - m
+            out = []
+            for m, padded in chunks:
+                with tr.wall("judge.prefill"):
+                    out.append(
+                        np.asarray(self._score(self.params, padded))[:m])
+            return np.concatenate(out).astype(np.float32) if out \
+                else np.zeros(0, np.float32)
 
     def staticity(self, query: str) -> int:
         # stable across processes (Python's hash() is salted per run,

@@ -28,6 +28,7 @@ import numpy as np
 
 from repro.core.semantic_element import SemanticElement
 from repro.kernels.platform import resolve_backend
+from repro.obs.trace import NULL_TRACER
 
 
 class RowIndex:
@@ -62,6 +63,12 @@ class RowIndex:
         # stage-1 passes by path: with ``backend`` they say which scan
         # actually ran (the chip smoke asserts the Pallas routed scan did)
         self.passes = {"routed": 0, "brute": 0}
+        # bytes the most recent search_batch put from host to device
+        # (kernel backend; ScanMetrics.h2d_bytes sums them per pass)
+        self.last_h2d_bytes = 0
+        # wall spans of the search (seri.search / .layout / .h2d /
+        # .scan / .select); bound by CortexCache.bind_tracer
+        self.tracer = NULL_TRACER
         # the kernel backend sets these (Pallas wrappers in kernels/ops)
         self._kernel_fn = None
         self._ivf_kernel_fn = None
@@ -113,6 +120,26 @@ class RowIndex:
 
     def _store_rows(self, ra: np.ndarray, embs: np.ndarray) -> None:
         raise NotImplementedError
+
+    def _put(self, *arrays, sharded=(), placement=None) -> tuple:
+        """Send a kernel pass's host operands to the device in one
+        ``jax.device_put`` (``sharded`` ones to ``placement``, the mesh
+        layout of a sharded scan), so the kernel wrappers receive device
+        arrays. Counts the bytes into ``last_h2d_bytes``. Under an
+        enabled tracer the ``seri.h2d`` span waits for the copy to land,
+        so that it holds the whole transfer; untraced, the copy stays
+        asynchronous and the kernel waits for it as it always did."""
+        import jax
+
+        tr = self.tracer
+        with tr.wall("seri.h2d"):
+            out = jax.device_put(arrays)
+            if sharded:
+                out += jax.device_put(sharded, placement)
+            if tr.enabled:
+                jax.block_until_ready(out)
+        self.last_h2d_bytes += sum(x.nbytes for x in out)
+        return out
 
     def add_batch(self, se_ids, embeddings) -> np.ndarray:
         """Bulk add for large prefills (the million-entry sweeps): one
@@ -317,24 +344,25 @@ class VectorIndex(RowIndex):
         the same -1.0 sentinel as the brute path's inactive mask, so at
         nprobe=all the scored matrix is exactly the brute matrix
         restricted to active rows — same values, same tie order."""
-        g_rows, allowed, self.last_scanned = routed
-        rt = self.router
-        s = np.where(allowed, q @ self.emb[g_rows].T, -1.0)
-        if rt.n_shards > 1:
-            # shard-parallel selection over the SAME score matrix: each
-            # shard top-k's its owned member columns, finalists merge
-            # under topk_desc's (value desc, row asc) order — so the
-            # result is bit-identical to the unsharded path and the
-            # float-reduction tolerance across shard counts is zero
-            owners = rt.shard_of[rt.assign[g_rows]]
-            n_cent = self.last_scanned - len(g_rows)
-            self.last_scanned_max_shard = n_cent + int(
-                np.bincount(owners, minlength=rt.n_shards).max())
-            lrows, sims = sharded_topk_merge(s, owners, rt.n_shards, k)
-        else:
-            self.last_scanned_max_shard = self.last_scanned
-            lrows, sims = topk_desc(s, k)                      # (B, k)
-        return g_rows[lrows], sims
+        with self.tracer.wall("seri.scan"):
+            g_rows, allowed, self.last_scanned = routed
+            rt = self.router
+            s = np.where(allowed, q @ self.emb[g_rows].T, -1.0)
+            if rt.n_shards > 1:
+                # shard-parallel selection over the SAME score matrix: each
+                # shard top-k's its owned member columns, finalists merge
+                # under topk_desc's (value desc, row asc) order — so the
+                # result is bit-identical to the unsharded path and the
+                # float-reduction tolerance across shard counts is zero
+                owners = rt.shard_of[rt.assign[g_rows]]
+                n_cent = self.last_scanned - len(g_rows)
+                self.last_scanned_max_shard = n_cent + int(
+                    np.bincount(owners, minlength=rt.n_shards).max())
+                lrows, sims = sharded_topk_merge(s, owners, rt.n_shards, k)
+            else:
+                self.last_scanned_max_shard = self.last_scanned
+                lrows, sims = topk_desc(s, k)                      # (B, k)
+            return g_rows[lrows], sims
 
     def _search_routed_kernel(self, q: np.ndarray, k: int):
         """Routed scan on the Pallas backend: routing (centroid scores +
@@ -344,18 +372,21 @@ class VectorIndex(RowIndex):
         rt = self.router
         if rt.n_shards > 1:
             return self._search_routed_kernel_sharded(q, k)
-        layout, bucket_rows, bucket_valid = rt.kernel_buckets(self)
+        tr = self.tracer
+        with tr.wall("seri.layout"):
+            layout, bucket_rows, bucket_valid = rt.kernel_buckets(self)
         nprobe = rt.cfg.n_clusters if rt.cfg.nprobe is None \
             else min(rt.cfg.nprobe, rt.cfg.n_clusters)
         live = rt.counts > 0
-        sims, rows, sel, en = self._ivf_kernel_fn(
-            rt.centroids, live.astype(np.int32), layout,
-            bucket_rows, bucket_valid, q, nprobe, k,
-        )
-        probed = np.unique(np.asarray(sel)[np.asarray(en) > 0])
+        args = self._put(rt.centroids, live.astype(np.int32), layout,
+                         bucket_rows, bucket_valid, q)
+        with tr.wall("seri.scan"):
+            sims, rows, sel, en = self._ivf_kernel_fn(*args, nprobe, k)
+            probed = np.unique(np.asarray(sel)[np.asarray(en) > 0])
+            rows, sims = np.asarray(rows), np.asarray(sims)
         self.last_scanned = int(live.sum() + rt.counts[probed].sum())
         self.last_scanned_max_shard = self.last_scanned
-        return np.asarray(rows), np.asarray(sims)
+        return rows, sims
 
     def _search_routed_kernel_sharded(self, q: np.ndarray, k: int):
         """Shard-parallel Pallas routed scan (DESIGN.md §13): routing
@@ -364,30 +395,43 @@ class VectorIndex(RowIndex):
         the S·nprobe·k finalists merge with one cross-shard
         ``lax.top_k``. Scan accounting splits the probed members by
         owner so the engine can charge max-over-shards."""
+        from repro.kernels.ann_topk_sharded import shard_placement
+
         rt = self.router
-        layout, shard_rows, shard_valid, bounds = \
-            rt.kernel_shard_buckets(self)
+        tr = self.tracer
+        with tr.wall("seri.layout"):
+            layout, shard_rows, shard_valid, bounds = \
+                rt.kernel_shard_buckets(self)
         nprobe = rt.cfg.n_clusters if rt.cfg.nprobe is None \
             else min(rt.cfg.nprobe, rt.cfg.n_clusters)
         live = rt.counts > 0
-        sims, rows, sel, en = self._ivf_sharded_fn(
-            rt.centroids, live.astype(np.int32), layout,
-            shard_rows, shard_valid, bounds, q, nprobe, k,
-        )
-        probed = np.unique(np.asarray(sel)[np.asarray(en) > 0])
+        cent, live_d, qd, layout, shard_rows, shard_valid = self._put(
+            rt.centroids, live.astype(np.int32), q,
+            sharded=(layout, shard_rows, shard_valid),
+            placement=shard_placement(rt.n_shards))
+        with tr.wall("seri.scan"):
+            sims, rows, sel, en = self._ivf_sharded_fn(
+                cent, live_d, layout, shard_rows, shard_valid, bounds, qd,
+                nprobe, k,
+            )
+            probed = np.unique(np.asarray(sel)[np.asarray(en) > 0])
+            rows, sims = np.asarray(rows), np.asarray(sims)
         n_cent = int(live.sum())
         per_shard = np.bincount(
             rt.shard_of[probed], weights=rt.counts[probed],
             minlength=rt.n_shards)
         self.last_scanned = n_cent + int(rt.counts[probed].sum())
         self.last_scanned_max_shard = n_cent + int(per_shard.max())
-        return np.asarray(rows), np.asarray(sims)
+        return rows, sims
 
     def _search_brute(self, q: np.ndarray, k: int):
         if self.backend == "kernel":
-            sims, rows = self._kernel_fn(self.emb, self.active, q, k)
-            return np.asarray(rows), np.asarray(sims)
-        return self.numpy_brute(q, k)
+            emb, active, qd = self._put(self.emb, self.active, q)
+            with self.tracer.wall("seri.scan"):
+                sims, rows = self._kernel_fn(emb, active, qd, k)
+                return np.asarray(rows), np.asarray(sims)
+        with self.tracer.wall("seri.scan"):
+            return self.numpy_brute(q, k)
 
     def numpy_brute(self, q: np.ndarray, k: int):
         """The numpy brute scan: the reference the Pallas kernel is
@@ -409,27 +453,32 @@ class VectorIndex(RowIndex):
         independently), so batching never changes retrieval semantics.
         """
         b = q.shape[0]
+        self.last_h2d_bytes = 0
         if len(self) == 0:
             self.last_scanned = 0
             self.last_scanned_max_shard = 0
             empty = ([], np.zeros(0, np.float32))
             return [empty] * b
-        q = np.asarray(q, np.float32)
-        rows, sims, routed = self._routed_dispatch(
-            q,
-            lambda: self._search_routed_kernel(q, k),
-            lambda info: self._search_routed(q, k, info),
-            lambda: self._search_brute(q, k),
-        )
-        out = []
-        for i in range(b):
-            keep = sims[i] >= tau_sim
-            if routed:
-                keep &= rows[i] >= 0   # kernel NEG slots carry row -1
-            r = rows[i][keep]
-            # row→se_id as ONE int64 gather (no per-candidate Python loop)
-            out.append((self.row_se[r].tolist(),
-                        sims[i][keep].astype(np.float32)))
+        tr = self.tracer
+        with tr.wall("seri.search"):
+            q = np.asarray(q, np.float32)
+            rows, sims, routed = self._routed_dispatch(
+                q,
+                lambda: self._search_routed_kernel(q, k),
+                lambda info: self._search_routed(q, k, info),
+                lambda: self._search_brute(q, k),
+            )
+            out = []
+            with tr.wall("seri.select"):
+                for i in range(b):
+                    keep = sims[i] >= tau_sim
+                    if routed:
+                        keep &= rows[i] >= 0   # kernel NEG slots: row -1
+                    r = rows[i][keep]
+                    # row→se_id as ONE int64 gather (no per-candidate
+                    # Python loop)
+                    out.append((self.row_se[r].tolist(),
+                                sims[i][keep].astype(np.float32)))
         return out
 
 

@@ -197,10 +197,9 @@ class QuantIndex(RowIndex):
         nprobe = rt.cfg.n_clusters if rt.cfg.nprobe is None \
             else min(rt.cfg.nprobe, rt.cfg.n_clusters)
         live = rt.counts > 0
-        vals, rows, sel, en = self._ivf_kernel_fn(
-            rt.centroids, live.astype(np.int32), bq,
-            bscale, bucket_rows, bucket_valid, q, qq, qs, nprobe, r,
-        )
+        args = self._put(rt.centroids, live.astype(np.int32), bq, bscale,
+                         bucket_rows, bucket_valid, q, qq, qs)
+        vals, rows, sel, en = self._ivf_kernel_fn(*args, nprobe, r)
         probed = np.unique(np.asarray(sel)[np.asarray(en) > 0])
         self.last_scanned = int(live.sum() + rt.counts[probed].sum())
         self.last_scanned_max_shard = self.last_scanned
@@ -211,15 +210,21 @@ class QuantIndex(RowIndex):
         ``VectorIndex._search_routed_kernel_sharded`` (DESIGN.md §13):
         global routing, per-shard Pallas scans under ``shard_map``, one
         cross-shard ``lax.top_k`` merge."""
+        from repro.kernels.ann_topk_sharded import shard_placement
+
         rt = self.router
         (bq, bscale), shard_rows, shard_valid, bounds = \
             rt.kernel_shard_buckets(self, quant=True)
         nprobe = rt.cfg.n_clusters if rt.cfg.nprobe is None \
             else min(rt.cfg.nprobe, rt.cfg.n_clusters)
         live = rt.counts > 0
+        cent, live_d, q, qq, qs, bq, bscale, shard_rows, shard_valid = \
+            self._put(rt.centroids, live.astype(np.int32), q, qq, qs,
+                      sharded=(bq, bscale, shard_rows, shard_valid),
+                      placement=shard_placement(rt.n_shards))
         vals, rows, sel, en = self._ivf_sharded_fn(
-            rt.centroids, live.astype(np.int32), bq, bscale,
-            shard_rows, shard_valid, bounds, q, qq, qs, nprobe, r,
+            cent, live_d, bq, bscale, shard_rows, shard_valid, bounds, q,
+            qq, qs, nprobe, r,
         )
         probed = np.unique(np.asarray(sel)[np.asarray(en) > 0])
         n_cent = int(live.sum())
@@ -233,7 +238,7 @@ class QuantIndex(RowIndex):
     def _coarse_brute(self, qq, qs, r: int):
         if self.backend == "kernel":
             vals, rows = self._kernel_fn(
-                self.emb_q, self.scale, self.active, qq, qs, r
+                *self._put(self.emb_q, self.scale, self.active, qq, qs), r
             )
             return np.asarray(rows), np.asarray(vals)
         return self.numpy_coarse_brute(qq, qs, r)
@@ -254,6 +259,7 @@ class QuantIndex(RowIndex):
         """q (B, dim) fp32 unit-norm -> list of B (se_ids, sims) pairs,
         similarity-descending, gated at tau_sim on the RESCORED sims."""
         b = q.shape[0]
+        self.last_h2d_bytes = 0
         if len(self) == 0:
             self.last_scanned = 0
             self.last_scanned_max_shard = 0
@@ -515,6 +521,11 @@ class TieredCache(CortexCache):
         self.warm = warm
         self.tier_stats = TierStats()
 
+    def bind_tracer(self, tracer) -> None:
+        """As :meth:`CortexCache.bind_tracer`, the warm index too."""
+        super().bind_tracer(tracer)
+        self.warm.index.tracer = tracer
+
     # --------------------------------------------------------- lifecycle
 
     def _demote_rows(self, rows: np.ndarray, now: float) -> None:
@@ -623,6 +634,7 @@ class TieredCache(CortexCache):
             self.scan.add_warm_pass(
                 self.warm.index.last_scanned,
                 self.warm.index.last_scanned_max_shard,
+                self.warm.index.last_h2d_bytes,
             )
             for bi, (wc, wsims) in zip(warm_qi, wfound):
                 # the consult FACT (flowing back through

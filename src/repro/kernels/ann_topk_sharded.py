@@ -37,7 +37,7 @@ from repro.kernels.ann_topk_ivf import NEG, ann_topk_ivf, ann_topk_ivf_quant
 from repro.kernels.platform import on_tpu
 
 __all__ = ["ann_topk_ivf_sharded", "ann_topk_ivf_quant_sharded",
-           "mesh_available", "mesh_scan", "NEG"]
+           "mesh_available", "mesh_scan", "shard_placement", "NEG"]
 
 
 def mesh_available(n_shards: int) -> bool:
@@ -51,6 +51,18 @@ def mesh_available(n_shards: int) -> bool:
             f"{n_shards} stage-1 shards need {n_shards} devices; this "
             f"host has {jax.device_count()}")
     return False
+
+
+def shard_placement(n_shards: int):
+    """Where a caller puts the per-shard stacks: one shard per device
+    (the mesh layout the ``shard_map`` program reads, so its own
+    ``device_put`` moves nothing) when the mesh path runs, else None,
+    the default device of the unrolled loop."""
+    if n_shards > 1 and mesh_available(n_shards):
+        from repro.launch.mesh import make_shard_mesh
+
+        return NamedSharding(make_shard_mesh(n_shards), P("shards"))
+    return None
 
 
 def _own_probes(sel, en, lo, hi, cmax):
@@ -102,16 +114,14 @@ def _run_mesh(n_shards: int, k: int, quant: bool, sharded, bounds,
               replicated):
     """Place the per-shard stacks (and each shard's cluster range) on
     their own devices straight from the host — never via one device —
-    then run the mesh program."""
-    from repro.launch.mesh import make_shard_mesh
-
-    mesh = make_shard_mesh(n_shards)
-    put = functools.partial(jax.device_put,
-                            device=NamedSharding(mesh, P("shards")))
+    then run the mesh program. Stacks the caller already placed with
+    :func:`shard_placement` stay where they are."""
+    placement = shard_placement(n_shards)
     lo = np.asarray(bounds[:-1], np.int32).reshape(n_shards, 1)
     hi = np.asarray(bounds[1:], np.int32).reshape(n_shards, 1)
-    args = [put(x) for x in (*sharded, lo, hi)]
-    return mesh_scan(mesh, k, quant)(*args, *map(jnp.asarray, replicated))
+    args = jax.device_put((*sharded, lo, hi), placement)
+    return mesh_scan(placement.mesh, k, quant)(
+        *args, *map(jnp.asarray, replicated))
 
 
 def ann_topk_ivf_sharded(sel, enabled, q, shard_buckets, shard_valid,

@@ -37,7 +37,12 @@ def ann_topk_jit(emb, active, q, k: int = 4):
     sublane count keeps the kernel's (B, D) block shape TPU-aligned and
     bounds jit retraces to one per padded size. Each query column is
     reduced independently inside the kernel, so the zero-padded rows are
-    sliced off without affecting real results."""
+    sliced off without affecting real results.
+
+    ``VectorIndex`` puts ``emb``, ``active`` and ``q`` on the device
+    itself, in one ``jax.device_put`` per pass (its ``seri.h2d`` span and
+    ``ScanMetrics.h2d_bytes``), so here the ``jnp.asarray`` calls move
+    nothing; a caller that passes host arrays has them copied here."""
     single = q.ndim == 1
     if single:
         q = q[None]
@@ -91,7 +96,11 @@ def ann_topk_ivf_jit(centroids, live, buckets, bucket_rows, bucket_valid,
     (scalar-prefetch Pallas kernel), merge per-probe finalists. Returns
     ``(vals (B, kk), rows (B, kk), sel, enabled)`` — rows are global
     index rows (-1 where masked); sel/enabled feed the host's
-    rows-scanned accounting."""
+    rows-scanned accounting.
+
+    As with :func:`ann_topk_jit`, ``VectorIndex`` puts every operand
+    (centroids, live mask, bucket layout, bucket rows and mask, queries)
+    on the device before the call; host arrays are copied here."""
     b = q.shape[0]
     pad = (-b) % _B_ALIGN
     q = jnp.asarray(q)
@@ -110,7 +119,8 @@ def ann_topk_ivf_quant_jit(centroids, live, buckets_q, bucket_scale,
     """Clustered QuantIndex backend adapter (coarse phase only): routing
     runs on the fp32 query against the fp32 centroids; the bucket scan
     is fully quantized (int8 × int8, int32 accumulate), mirroring the
-    brute ``ann_topk_quant`` coarse/rescore split."""
+    brute ``ann_topk_quant`` coarse/rescore split. ``QuantIndex`` puts
+    the operands on the device before the call."""
     b = qq.shape[0]
     pad = (-b) % _B_ALIGN
     q, qq, q_scales = jnp.asarray(q), jnp.asarray(qq), jnp.asarray(q_scales)
@@ -194,6 +204,7 @@ def ann_topk_quant_jit(emb_q, scales, active, qq, q_scales, k: int = 16):
     the same routine the numpy path uses, so both backends score identical
     integers. B is padded to the sublane multiple like ``ann_topk_jit``;
     padded query lanes carry scale 0 (all-zero scores) and are sliced off.
+    ``QuantIndex`` puts the operands on the device before the call.
     """
     b = qq.shape[0]
     pad = (-b) % _B_ALIGN

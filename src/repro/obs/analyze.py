@@ -249,3 +249,22 @@ def format_attribution(report: Mapping) -> str:
                 f"{seg['p50']:>9.4f}{seg['p99']:>9.4f}"
             )
     return "\n".join(lines)
+
+
+def wall_breakdown(wall_spans, start: int = 0) -> dict:
+    """Per wall-span name over ``wall_spans[start:]`` (a window's share of
+    ``Tracer.wall_spans``): ``n`` spans, ``total_s`` summed wall seconds
+    and ``self_s``, each span's duration less its direct children's."""
+    spans = wall_spans[start:]
+    child_s = [0.0] * len(spans)
+    for s in spans:
+        if s.parent >= start:
+            child_s[s.parent - start] += s.t1 - s.t0
+    out: dict[str, dict] = {}
+    for s, c in zip(spans, child_s):
+        blk = out.setdefault(s.name, {"n": 0, "total_s": 0.0,
+                                      "self_s": 0.0})
+        blk["n"] += 1
+        blk["total_s"] += s.t1 - s.t0
+        blk["self_s"] += s.t1 - s.t0 - c
+    return out

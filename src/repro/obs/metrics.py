@@ -157,15 +157,19 @@ class ScanMetrics:
     queries share one scan. ``last_max_shard_rows`` is the busiest
     shard's slice of the last pass — the quantity the §13 latency model
     charges (shards stream in parallel); at one shard it equals
-    ``last_rows``.
+    ``last_rows``. ``h2d_bytes`` is the cumulative bytes the passes put
+    from host to device (the kernel backend's operands, counted by the
+    index as it puts them; 0 on the numpy backend).
     """
 
     last_rows: int = 0            # rows scanned by the last stage-1 pass
     total_rows: int = 0           # cumulative rows over all passes
     last_max_shard_rows: int = 0  # busiest shard's rows, last pass (§13)
     total_max_shard_rows: int = 0 # cumulative max-shard rows
+    h2d_bytes: int = 0            # cumulative host-to-device bytes
 
-    def note_pass(self, rows: int, max_shard_rows: int | None = None) -> None:
+    def note_pass(self, rows: int, max_shard_rows: int | None = None,
+                  h2d_bytes: int = 0) -> None:
         """Record one stage-1 pass. ``max_shard_rows`` defaults to
         ``rows`` (the unsharded index IS one shard)."""
         m = rows if max_shard_rows is None else max_shard_rows
@@ -173,8 +177,10 @@ class ScanMetrics:
         self.total_rows += int(rows)
         self.last_max_shard_rows = int(m)
         self.total_max_shard_rows += int(m)
+        self.h2d_bytes += int(h2d_bytes)
 
-    def add_warm_pass(self, rows: int, max_shard_rows: int | None = None) -> None:
+    def add_warm_pass(self, rows: int, max_shard_rows: int | None = None,
+                      h2d_bytes: int = 0) -> None:
         """Fold a warm-tier consult into the CURRENT pass (§10): the hot
         and warm scans of one flush count as one pass's volume."""
         m = rows if max_shard_rows is None else max_shard_rows
@@ -182,6 +188,7 @@ class ScanMetrics:
         self.total_rows += int(rows)
         self.last_max_shard_rows += int(m)
         self.total_max_shard_rows += int(m)
+        self.h2d_bytes += int(h2d_bytes)
 
 
 class MetricsRegistry:

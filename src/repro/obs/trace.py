@@ -1,6 +1,9 @@
-"""Request-lifecycle tracing in virtual time (DESIGN.md §15).
+"""Request-lifecycle tracing in virtual time, and wall-clock spans of the
+served path (DESIGN.md §15).
 
-A :class:`Tracer` records *spans*: half-open ``[t0, t1)`` segments of
+A :class:`Tracer` keeps two span lists that never mix.
+
+``spans`` are *virtual-time* spans: half-open ``[t0, t1)`` segments of
 virtual time attributed to one request (``rid >= 0``) or to background
 work (``rid == BACKGROUND``). Spans are emitted by the serving layers at
 the instants they already know exactly — the engine's stage-1 flush, the
@@ -47,10 +50,27 @@ singleton whose ``span`` is an empty method, and every instrumentation
 site either calls it directly (cold paths) or guards a loop with
 ``tracer.enabled`` (the per-batch hot paths), so an untraced engine does
 no per-span work at all.
+
+``wall_spans`` are *wall-clock* spans of the served lookup path, drawn
+by the program around its own layer boundaries with ``tracer.wall(name)``
+once a tracer is bound (``CortexCache.bind_tracer``,
+``ModelEmbedder.bind_tracer``). Each is a :class:`WallSpan`: its name,
+start and end on ``time.perf_counter``, the index of its parent (the
+innermost wall span open when it started, -1 for none) and a call id
+shared by every span under one root span (one call into the cache or
+the embedder). Each also opens a ``jax.profiler.TraceAnnotation`` of the
+same name, so under the profiler its host event shares the device
+trace's clock and device idle time can be charged to it. Wall span
+names (``cache.lookup_batch``, ``seri.h2d``, ``judge.prefill``, ...) are
+dotted ``<layer>.<step>`` and never reuse a virtual-time span's name.
+They are kept out of ``spans``, so the conservation law and the JSONL /
+Chrome exports are unchanged by them. ``NullTracer.wall`` returns one
+shared no-op context: no allocation, no annotation, no clock read.
 """
 from __future__ import annotations
 
-from typing import Optional
+from time import perf_counter
+from typing import NamedTuple, Optional
 
 # rid used for spans that belong to no request (refresh-ahead fetches,
 # invalidation drops, holder-side lease validation)
@@ -61,18 +81,74 @@ BACKGROUND = -1
 RID, NAME, T0, T1, REGION, TAG = range(6)
 
 
+class WallSpan(NamedTuple):
+    """One closed wall-clock span; ``parent`` indexes ``wall_spans``."""
+    name: str
+    t0: float
+    t1: float
+    parent: int
+    call: int
+
+
+class _Wall:
+    """The context of one open wall span (enabled tracer only)."""
+
+    __slots__ = ("tr", "name", "i", "parent", "call", "ann", "t0")
+
+    def __init__(self, tr: "Tracer", name: str):
+        self.tr, self.name = tr, name
+
+    def __enter__(self):
+        tr = self.tr
+        if tr._open:
+            self.parent, self.call = tr._open[-1]
+        else:
+            self.parent, self.call = -1, tr._calls
+            tr._calls += 1
+        # the slot is taken at the start, so a parent's index is known
+        # to its children; it holds None until the span closes
+        self.i = len(tr.wall_spans)
+        tr.wall_spans.append(None)
+        tr._open.append((self.i, self.call))
+        self.ann = tr._annotation(self.name)
+        self.ann.__enter__()
+        self.t0 = perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = perf_counter()
+        self.ann.__exit__(*exc)
+        tr = self.tr
+        tr._open.pop()
+        tr.wall_spans[self.i] = WallSpan(self.name, self.t0, t1,
+                                         self.parent, self.call)
+        return False
+
+
 class Tracer:
     """Span sink shared by every layer of one run (one per engine, or one
     per federation — spans carry the region id either way)."""
 
     enabled = True
-    __slots__ = ("spans",)
+    __slots__ = ("spans", "wall_spans", "_open", "_calls", "_annotation")
 
     def __init__(self):
+        from jax.profiler import TraceAnnotation
+
         # (rid, name, t0, t1, region, tag) in emission order — which is
         # deterministic (clock event order), making the exported JSONL
         # byte-identical across same-seed runs
         self.spans: list[tuple] = []
+        # WallSpan per wall span, in start order (None while open)
+        self.wall_spans: list = []
+        self._open: list[tuple[int, int]] = []    # (index, call) stack
+        self._calls = 0
+        self._annotation = TraceAnnotation
+
+    def wall(self, name: str) -> _Wall:
+        """Context manager: a wall-clock span ``name`` around the block,
+        nested under the innermost wall span open."""
+        return _Wall(self, name)
 
     def span(self, rid: int, name: str, t0: float, t1: float,
              region: int = 0, tag: Optional[str] = None) -> None:
@@ -95,6 +171,21 @@ class Tracer:
         return out
 
 
+class _NullWall:
+    """The one shared no-op wall span of the disabled tracer."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+_NULL_WALL = _NullWall()
+
+
 class NullTracer:
     """Disabled tracer: every method is a no-op, no state, no allocation.
     The singleton :data:`NULL_TRACER` is the default everywhere a tracer
@@ -102,6 +193,9 @@ class NullTracer:
 
     enabled = False
     __slots__ = ()
+
+    def wall(self, name: str) -> _NullWall:
+        return _NULL_WALL
 
     def span(self, rid, name, t0, t1, region=0, tag=None) -> None:
         return None
