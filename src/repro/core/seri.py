@@ -22,13 +22,30 @@ identical results.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core.semantic_element import SemanticElement
 from repro.kernels.platform import resolve_backend
 from repro.obs.trace import NULL_TRACER
+
+# rows per in-place update of a device mirror: the one scatter shape that
+# compiles, whatever the number of dirty rows (padding rows are dropped)
+SYNC_CHUNK = 64
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _sync_rows(mirror, rows, vals):
+    """The row update of a device mirror: ``mirror`` (a tuple of row
+    arrays) takes ``vals`` at ``rows``; rows out of range are dropped.
+    The mirror's buffers are donated, so XLA writes the rows in place and
+    the device never holds two copies of the index."""
+    return tuple(a.at[rows].set(v, mode="drop")
+                 for a, v in zip(mirror, vals))
 
 
 class RowIndex:
@@ -40,7 +57,10 @@ class RowIndex:
 
     ``row_se`` is an int64 array (-1 = free) so batched search paths
     resolve row→se_id with one fancy-indexed gather instead of a
-    per-candidate Python loop. An optional
+    per-candidate Python loop. On the kernel backend the arrays the
+    brute scan reads (``_mirrored``) also live on the device, synced by
+    the rows changed since the last pass (``_sync_mirror``, DESIGN.md
+    §8); the host arrays stay authoritative. An optional
     :class:`~repro.core.clustering.ClusterRouter` observes the row
     lifecycle (``note_add``/``note_remove``) to keep its cluster
     buckets free-list-consistent (DESIGN.md §12)."""
@@ -64,8 +84,16 @@ class RowIndex:
         # actually ran (the chip smoke asserts the Pallas routed scan did)
         self.passes = {"routed": 0, "brute": 0}
         # bytes the most recent search_batch put from host to device
-        # (kernel backend; ScanMetrics.h2d_bytes sums them per pass)
+        # (kernel backend; ScanMetrics.h2d_bytes sums them per pass), and
+        # how its mirror sync went: whole-array puts, rows scattered
         self.last_h2d_bytes = 0
+        self.last_full_uploads = 0
+        self.last_synced_rows = 0
+        # the device mirror of _mirrored() (kernel backend, built by the
+        # first brute pass) and the rows changed on the host since its
+        # last sync
+        self._mirror = None
+        self._dirty: set = set()
         # wall spans of the search (seri.search / .layout / .h2d /
         # .scan / .select); bound by CortexCache.bind_tracer
         self.tracer = NULL_TRACER
@@ -88,9 +116,22 @@ class RowIndex:
         row = self._free.pop()
         self.active[row] = True
         self.row_se[row] = se_id
+        self._touch(row)
         return row
 
+    def _touch(self, rows) -> None:
+        """Note rows whose host values changed (every write to a row
+        passes here: ``_alloc``, ``add_batch``, ``remove_rows``) for the
+        mirror's next sync. Without a mirror there is nothing to note:
+        its first sync puts the whole arrays."""
+        if self._mirror is not None:
+            self._dirty.update(np.atleast_1d(rows).tolist())
+
     def _clear_rows(self, ra: np.ndarray) -> None:
+        raise NotImplementedError
+
+    def _mirrored(self) -> tuple:
+        """The host row arrays the brute kernel scans, in its order."""
         raise NotImplementedError
 
     def _routed_dispatch(self, q: np.ndarray, kernel_scan, routed_scan,
@@ -107,6 +148,8 @@ class RowIndex:
         if self.backend == "kernel":
             if ready and np.any(self.router.counts > 0):
                 self.passes["routed"] += 1
+                # the routed scan reads the bucket layout, not the rows
+                self._mirror, self._dirty = None, set()
                 return (*kernel_scan(), True)
         elif ready:
             info = self.router.route(q)
@@ -121,24 +164,74 @@ class RowIndex:
     def _store_rows(self, ra: np.ndarray, embs: np.ndarray) -> None:
         raise NotImplementedError
 
-    def _put(self, *arrays, sharded=(), placement=None) -> tuple:
+    def _new_pass(self) -> None:
+        """Zero the per-pass transfer counters (``last_h2d_bytes``,
+        ``last_full_uploads``, ``last_synced_rows``)."""
+        self.last_h2d_bytes = 0
+        self.last_full_uploads = 0
+        self.last_synced_rows = 0
+
+    def _sync_mirror(self) -> tuple:
+        """The device mirror of ``_mirrored()``, brought up to date.
+
+        Without a mirror, or with more dirty rows than scattering them
+        is worth (their padded chunks would move at least the bytes of
+        the whole arrays), one ``jax.device_put`` of the whole arrays;
+        the scatter then runs once on an all-padding chunk made on the
+        device, so its one shape compiles with the mirror and never
+        inside a served window. Otherwise only the dirty rows move, in
+        chunks of ``SYNC_CHUNK`` (padded with out-of-range rows), each
+        scattered in place by ``_sync_rows``. Counts the bytes into
+        ``last_h2d_bytes``."""
+        host = self._mirrored()
+        whole = sum(a.nbytes for a in host)
+        rows = np.array(sorted(self._dirty), np.int32)
+        self._dirty = set()
+        n_pad = -(-len(rows) // SYNC_CHUNK) * SYNC_CHUNK
+        row_bytes = whole // self.capacity + rows.itemsize
+        if self._mirror is None or n_pad * row_bytes >= whole:
+            self._mirror = None            # free the stale copy first
+            self._mirror = _sync_rows(
+                jax.device_put(host),
+                jnp.full(SYNC_CHUNK, self.capacity, jnp.int32),
+                tuple(jnp.zeros((SYNC_CHUNK,) + a.shape[1:], a.dtype)
+                      for a in host))
+            self.last_h2d_bytes += whole
+            self.last_full_uploads += 1
+            return self._mirror
+        for off in range(0, len(rows), SYNC_CHUNK):
+            part = rows[off:off + SYNC_CHUNK]
+            chunk = np.full(SYNC_CHUNK, self.capacity, np.int32)
+            chunk[:len(part)] = part
+            src = np.minimum(chunk, self.capacity - 1)
+            r, *vals = jax.device_put((chunk, *(a[src] for a in host)))
+            self._mirror = _sync_rows(self._mirror, r, tuple(vals))
+            self.last_h2d_bytes += r.nbytes + sum(v.nbytes for v in vals)
+        self.last_synced_rows += len(rows)
+        return self._mirror
+
+    def _put(self, *arrays, sharded=(), placement=None,
+             mirror: bool = False) -> tuple:
         """Send a kernel pass's host operands to the device in one
         ``jax.device_put`` (``sharded`` ones to ``placement``, the mesh
         layout of a sharded scan), so the kernel wrappers receive device
-        arrays. Counts the bytes into ``last_h2d_bytes``. Under an
-        enabled tracer the ``seri.h2d`` span waits for the copy to land,
-        so that it holds the whole transfer; untraced, the copy stays
-        asynchronous and the kernel waits for it as it always did."""
-        import jax
-
+        arrays. With ``mirror`` the device mirror of the brute scan's row
+        arrays comes first, synced by its dirty rows (``_sync_mirror``):
+        a brute pass sends only those rows and its queries, not the
+        index. Counts the bytes into ``last_h2d_bytes``. Under an
+        enabled tracer the ``seri.h2d`` span waits for the copies to
+        land, so that it holds the whole transfer; untraced, they stay
+        asynchronous and the kernel waits for them as it always did."""
         tr = self.tracer
         with tr.wall("seri.h2d"):
-            out = jax.device_put(arrays)
+            out = self._sync_mirror() if mirror else ()
+            put = jax.device_put(arrays)
             if sharded:
-                out += jax.device_put(sharded, placement)
+                put += jax.device_put(sharded, placement)
+            out += put
             if tr.enabled:
                 jax.block_until_ready(out)
-        self.last_h2d_bytes += sum(x.nbytes for x in out)
+        self.last_h2d_bytes += sum(x.nbytes for x in put)
         return out
 
     def add_batch(self, se_ids, embeddings) -> np.ndarray:
@@ -176,6 +269,7 @@ class RowIndex:
             self.active[ra] = True
             self.row_se[ra] = ids[i:i + take]
             self._store_rows(ra, embs[i:i + take])
+            self._touch(ra)
             rows[i:i + take] = ra
             if rt is not None:
                 rt.note_add_batch(ra, embs[i:i + take], self)
@@ -191,6 +285,7 @@ class RowIndex:
         self.active[ra] = False
         self._clear_rows(ra)
         self.row_se[ra] = -1
+        self._touch(ra)
         if self.router is not None:
             self.router.note_remove(ra)
         for r in rows:
@@ -327,6 +422,9 @@ class VectorIndex(RowIndex):
     def _clear_rows(self, ra: np.ndarray) -> None:
         self.emb[ra] = 0.0
 
+    def _mirrored(self) -> tuple:
+        return self.emb, self.active
+
     def route_embs(self, rows: np.ndarray) -> np.ndarray:
         """Unit-norm fp32 rows for centroid training/assignment."""
         return self.emb[rows]
@@ -426,7 +524,7 @@ class VectorIndex(RowIndex):
 
     def _search_brute(self, q: np.ndarray, k: int):
         if self.backend == "kernel":
-            emb, active, qd = self._put(self.emb, self.active, q)
+            emb, active, qd = self._put(q, mirror=True)
             with self.tracer.wall("seri.scan"):
                 sims, rows = self._kernel_fn(emb, active, qd, k)
                 return np.asarray(rows), np.asarray(sims)
@@ -453,7 +551,7 @@ class VectorIndex(RowIndex):
         independently), so batching never changes retrieval semantics.
         """
         b = q.shape[0]
-        self.last_h2d_bytes = 0
+        self._new_pass()
         if len(self) == 0:
             self.last_scanned = 0
             self.last_scanned_max_shard = 0

@@ -138,6 +138,9 @@ class QuantIndex(RowIndex):
         self._emb_exact[ra] = 0
         self.scale[ra] = 0.0
 
+    def _mirrored(self) -> tuple:
+        return self.emb_q, self.scale, self.active
+
     def route_embs(self, rows: np.ndarray) -> np.ndarray:
         """Dequantized, renormalized fp32 rows for centroid training —
         the router sees (near enough) the same vectors the fine rescore
@@ -237,9 +240,8 @@ class QuantIndex(RowIndex):
 
     def _coarse_brute(self, qq, qs, r: int):
         if self.backend == "kernel":
-            vals, rows = self._kernel_fn(
-                *self._put(self.emb_q, self.scale, self.active, qq, qs), r
-            )
+            vals, rows = self._kernel_fn(*self._put(qq, qs, mirror=True),
+                                         r)
             return np.asarray(rows), np.asarray(vals)
         return self.numpy_coarse_brute(qq, qs, r)
 
@@ -259,7 +261,7 @@ class QuantIndex(RowIndex):
         """q (B, dim) fp32 unit-norm -> list of B (se_ids, sims) pairs,
         similarity-descending, gated at tau_sim on the RESCORED sims."""
         b = q.shape[0]
-        self.last_h2d_bytes = 0
+        self._new_pass()
         if len(self) == 0:
             self.last_scanned = 0
             self.last_scanned_max_shard = 0
@@ -631,10 +633,11 @@ class TieredCache(CortexCache):
             # the warm coarse scan's rows join the pass's scan-
             # proportional latency term (DESIGN.md §12); its busiest
             # shard joins the max-over-shards critical path (§13)
+            wix = self.warm.index
             self.scan.add_warm_pass(
-                self.warm.index.last_scanned,
-                self.warm.index.last_scanned_max_shard,
-                self.warm.index.last_h2d_bytes,
+                wix.last_scanned, wix.last_scanned_max_shard,
+                wix.last_h2d_bytes, wix.last_full_uploads,
+                wix.last_synced_rows,
             )
             for bi, (wc, wsims) in zip(warm_qi, wfound):
                 # the consult FACT (flowing back through

@@ -39,10 +39,12 @@ def ann_topk_jit(emb, active, q, k: int = 4):
     reduced independently inside the kernel, so the zero-padded rows are
     sliced off without affecting real results.
 
-    ``VectorIndex`` puts ``emb``, ``active`` and ``q`` on the device
-    itself, in one ``jax.device_put`` per pass (its ``seri.h2d`` span and
-    ``ScanMetrics.h2d_bytes``), so here the ``jnp.asarray`` calls move
-    nothing; a caller that passes host arrays has them copied here."""
+    ``VectorIndex`` passes device arrays: ``emb`` and ``active`` from its
+    device mirror, which stays resident between passes and takes only the
+    rows changed since the last one, and ``q`` put per pass (its
+    ``seri.h2d`` span and ``ScanMetrics.h2d_bytes``), so here the
+    ``jnp.asarray`` calls move nothing; a caller that passes host arrays
+    has them copied here."""
     single = q.ndim == 1
     if single:
         q = q[None]
@@ -204,7 +206,8 @@ def ann_topk_quant_jit(emb_q, scales, active, qq, q_scales, k: int = 16):
     the same routine the numpy path uses, so both backends score identical
     integers. B is padded to the sublane multiple like ``ann_topk_jit``;
     padded query lanes carry scale 0 (all-zero scores) and are sliced off.
-    ``QuantIndex`` puts the operands on the device before the call.
+    ``QuantIndex`` passes device arrays: ``emb_q``, ``scales`` and
+    ``active`` from its resident mirror, the queries put per pass.
     """
     b = qq.shape[0]
     pad = (-b) % _B_ALIGN

@@ -159,7 +159,10 @@ class ScanMetrics:
     charges (shards stream in parallel); at one shard it equals
     ``last_rows``. ``h2d_bytes`` is the cumulative bytes the passes put
     from host to device (the kernel backend's operands, counted by the
-    index as it puts them; 0 on the numpy backend).
+    index as it puts them; 0 on the numpy backend). Of those, the brute
+    scan's row arrays stay on the device between passes (DESIGN.md §8):
+    ``full_uploads`` counts the passes that put them whole, and
+    ``synced_rows`` the dirty rows scattered into them in place.
     """
 
     last_rows: int = 0            # rows scanned by the last stage-1 pass
@@ -167,28 +170,35 @@ class ScanMetrics:
     last_max_shard_rows: int = 0  # busiest shard's rows, last pass (§13)
     total_max_shard_rows: int = 0 # cumulative max-shard rows
     h2d_bytes: int = 0            # cumulative host-to-device bytes
+    full_uploads: int = 0         # whole-index puts of the device mirror
+    synced_rows: int = 0          # dirty rows scattered into the mirror
 
     def note_pass(self, rows: int, max_shard_rows: int | None = None,
-                  h2d_bytes: int = 0) -> None:
+                  h2d_bytes: int = 0, full_uploads: int = 0,
+                  synced_rows: int = 0) -> None:
         """Record one stage-1 pass. ``max_shard_rows`` defaults to
         ``rows`` (the unsharded index IS one shard)."""
         m = rows if max_shard_rows is None else max_shard_rows
         self.last_rows = int(rows)
-        self.total_rows += int(rows)
         self.last_max_shard_rows = int(m)
-        self.total_max_shard_rows += int(m)
-        self.h2d_bytes += int(h2d_bytes)
+        self._add(rows, m, h2d_bytes, full_uploads, synced_rows)
 
     def add_warm_pass(self, rows: int, max_shard_rows: int | None = None,
-                      h2d_bytes: int = 0) -> None:
+                      h2d_bytes: int = 0, full_uploads: int = 0,
+                      synced_rows: int = 0) -> None:
         """Fold a warm-tier consult into the CURRENT pass (§10): the hot
         and warm scans of one flush count as one pass's volume."""
         m = rows if max_shard_rows is None else max_shard_rows
         self.last_rows += int(rows)
-        self.total_rows += int(rows)
         self.last_max_shard_rows += int(m)
+        self._add(rows, m, h2d_bytes, full_uploads, synced_rows)
+
+    def _add(self, rows, m, h2d_bytes, full_uploads, synced_rows) -> None:
+        self.total_rows += int(rows)
         self.total_max_shard_rows += int(m)
         self.h2d_bytes += int(h2d_bytes)
+        self.full_uploads += int(full_uploads)
+        self.synced_rows += int(synced_rows)
 
 
 class MetricsRegistry:

@@ -13,6 +13,7 @@ import pytest
 from repro.core.cache import make_cache
 from repro.core.clustering import ClusterConfig
 from repro.core.embedder import ModelEmbedder
+from repro.core.seri import SYNC_CHUNK
 from repro.core.judge import ModelJudge
 from repro.core.judge_pipeline import JudgePipeline
 from repro.launch.serve import run_once
@@ -153,14 +154,28 @@ def test_null_tracer_records_nothing(judge_model, monkeypatch):
     assert n_h2d == 2 and len(synced) == n_h2d
 
 
-def test_brute_pass_counts_its_bytes(judge_model):
+@pytest.mark.parametrize("case", ["first", "clean", "dirty"])
+def test_brute_pass_counts_its_bytes(judge_model, case):
+    """The first brute pass puts the whole index; later passes keep it on
+    the device and send their queries and the rows changed since."""
     cache, embs = _cache(judge_model)
     ix = cache.seri.index
+    queries = 3 * DIM * 4
+    want = ix.emb.nbytes + ix.active.nbytes + queries
+    if case != "first":
+        cache.lookup_batch(["x"], _queries(embs, [5]), now=0.5)
+        want = queries
+    if case == "dirty":
+        ix.add(10 ** 6, embs[7])
+        ix.remove_rows([0])
+        # one chunk of rows: fp32 row, mask byte, int32 row index
+        want += SYNC_CHUNK * (DIM * 4 + 1 + 4)
     before = cache.scan.h2d_bytes
-    cache.lookup_batch(["a", "b", "c"], _queries(embs, [0, 1, 2]), now=1.0)
-    want = ix.emb.nbytes + ix.active.nbytes + 3 * DIM * 4
+    cache.lookup_batch(["a", "b", "c"], _queries(embs, [1, 2, 3]), now=1.0)
     assert ix.last_h2d_bytes == want
     assert cache.scan.h2d_bytes - before == want
+    assert cache.scan.full_uploads == 1
+    assert cache.scan.synced_rows == (2 if case == "dirty" else 0)
 
 
 @pytest.mark.parametrize("shards", [1, 2])
