@@ -215,9 +215,11 @@ def _sample(n: int, rng) -> np.ndarray:
     return np.sort(rng.choice(n, min(n, SAMPLE), replace=False))
 
 
-def embed_gap(cfg, weights, embeds, rng, control: bool) -> list:
+def embed_gap(model, max_len: int, weights, embeds, rng,
+              control: bool) -> list:
     """Widest 1 - cos between the window's embeddings and the reference
-    forward of the same texts: [program, control or None]."""
+    forward of the same texts by the embedder's ``model``
+    (``model_ref.Model``): [program, control or None]."""
     texts, got = [], []
     for t, e in embeds:
         texts.extend(t)
@@ -226,11 +228,9 @@ def embed_gap(cfg, weights, embeds, rng, control: bool) -> list:
         return [0.0, 0.0 if control else None]
     got = np.concatenate(got)
     pick = _sample(len(texts), rng)
-    dep = cfg["deployment"]
-    toks = model_ref.byte_tokens([texts[i] for i in pick],
-                                 dep["embed_max_len"],
-                                 int(cfg["vocab_size"]))
-    ref = model_ref.embed_ref(weights, toks, cfg).astype(np.float64)
+    toks = model_ref.byte_tokens([texts[i] for i in pick], max_len,
+                                 int(model.hf["vocab_size"]))
+    ref = model_ref.embed_ref(model, weights, toks).astype(np.float64)
 
     def gap(mine):
         cos = np.sum(mine * ref, 1) / (np.linalg.norm(mine, axis=1)
@@ -238,12 +238,13 @@ def embed_gap(cfg, weights, embeds, rng, control: bool) -> list:
         return float(np.max(1.0 - cos))
 
     return [gap(got[pick]), gap(model_ref.embed_ref(
-        weights, toks, cfg, control=True)) if control else None]
+        model, weights, toks, control=True)) if control else None]
 
 
-def judge_gap(cfg, weights, calls, rng, control: bool) -> list:
-    """Widest |logit - reference logit| over sampled judged pairs:
-    [program, control or None]."""
+def judge_gap(model, max_len: int, weights, calls, rng,
+              control: bool) -> list:
+    """Widest |logit - reference logit| over sampled judged pairs, by the
+    judge's ``model`` (``model_ref.Model``): [program, control or None]."""
     pairs, scores = [], []
     for q, c, s in calls:
         pairs.extend(zip(q, c))
@@ -251,16 +252,15 @@ def judge_gap(cfg, weights, calls, rng, control: bool) -> list:
     if not pairs:
         return [0.0, 0.0 if control else None]
     pick = _sample(len(pairs), rng)
-    dep = cfg["deployment"]
     toks = model_ref.byte_tokens([f"{pairs[i][0]} [SEP] {pairs[i][1]}"
-                                  for i in pick], dep["judge_max_len"],
-                                 int(cfg["vocab_size"]))
-    ref = model_ref.judge_logit_ref(weights, toks, cfg)
+                                  for i in pick], max_len,
+                                 int(model.hf["vocab_size"]))
+    ref = model_ref.judge_logit_ref(model, weights, toks)
     s = np.clip(np.asarray(scores)[pick], 1e-12, 1 - 1e-12)
     out = [float(np.max(np.abs(np.log(s) - np.log1p(-s) - ref)))]
     if control:
         out.append(float(np.max(np.abs(model_ref.judge_logit_ref(
-            weights, toks, cfg, control=True) - ref))))
+            model, weights, toks, control=True) - ref))))
     else:
         out.append(None)
     return out

@@ -1,9 +1,11 @@
 """Operations and bytes that the served path's work needs, from shapes.
 
-These are the yardstick for ``scan_roofline`` and ``lookup_mfu``: they
-count what the algorithm requires for the shapes a run actually asked
-for, never what a particular implementation happens to do (padding rows
-or recomputing a causal mask's upper half count for nothing).
+These are the yardstick for ``scan_roofline``, ``judge_roofline`` and
+``lookup_mfu``: they count what the algorithm requires for the shapes a
+run actually asked for, never what a particular implementation happens
+to do (padding rows or recomputing a causal mask's upper half count for
+nothing). A model's counts are its family file's (``models/``:
+``forward_flops``, ``weight_bytes``); the stage-1 scans' are here.
 """
 from __future__ import annotations
 
@@ -22,20 +24,6 @@ def peaks(device_kind: str) -> dict:
         raise KeyError(f"no peaks for device kind {device_kind!r} in "
                        "peaks.json")
     return table[device_kind]
-
-
-def lm_forward_flops(cfg: dict, seq: int) -> float:
-    """FLOPs of one qwen3-family forward over ``seq`` tokens, up to the
-    last hidden state (no logits): projections and feed-forward per
-    token, causal attention over the pairs (i, j <= i)."""
-    d = int(cfg["hidden_size"])
-    hq = int(cfg["num_attention_heads"]) * int(cfg["head_dim"])
-    hk = int(cfg["num_key_value_heads"]) * int(cfg["head_dim"])
-    ff = int(cfg["intermediate_size"])
-    per_tok = 2 * d * (2 * hq + 2 * hk) + 2 * 3 * d * ff
-    pairs = seq * (seq + 1) // 2
-    attn = 2 * 2 * pairs * hq              # q.k and p.v
-    return float(int(cfg["num_hidden_layers"]) * (seq * per_tok + attn))
 
 
 def ann_topk(B: int, N: int, D: int, itemsize: int = 4) -> tuple:

@@ -1,27 +1,46 @@
-"""Weights for the served qwen3-family models, and their plain reference.
+"""The served models: each found by its configuration's own
+``model_type``, and the plain reference's parts that every family shares.
+
+A configuration file may hold a ``judge_model`` and an ``embedder_model``
+object, each a published ``config.json``; one that has neither serves
+its own top-level config as both. Each config's ``model_type`` names its
+family file, ``models/<model_type>.py``, found by name as the traffic
+generators and the per-layer readers are. A family file provides
+
+    program_config(hf, name)   the program's ModelConfig
+    make_weights(hf, seed)     the served parameter tree, drawn on the
+                               device in one jitted call
+    hidden(w, tokens, hf, control=False)
+                               the plain float32 reference's last hidden
+                               state, layer by layer at HIGHEST precision
+    forward_flops(hf, seq)     FLOPs one forward over seq tokens needs
+    weight_bytes(hf)           bytes of the weights one forward reads
+
+so a new architecture is a new file there, never an edit.
 
 The benchmark makes the judge's and the embedder's weights itself, from
-the seed, in one jitted call each, in the type they are served in
-(bfloat16 matrices, float32 norm scales), and hands them to the program.
-The reference below imports nothing of the program: it is a plain
-float32 forward written from the configuration file, with every matmul
-at HIGHEST precision, run layer by layer so that it fits beside whatever
-else is on the chip. With ``control=True`` every matmul operand is
-rounded to float8 (e4m3) first: the precision one step below the
-configuration's bfloat16, which the comparison has to reject.
+the seed, in the type they are served in, and hands them to the program.
+The reference imports nothing of the program: it is a plain float32
+forward written from the published config, with every matmul at HIGHEST
+precision, run layer by layer so that it fits beside whatever else is on
+the chip. With ``control=True`` every matmul operand is rounded to
+float8 (e4m3) first: the precision one step below the configuration's
+bfloat16, which the comparison has to reject.
 
-What the program computes, and so what the reference computes too
-(departures from Qwen3, recorded in the configuration files):
+What the program computes around any family's forward, and so what the
+reference computes too (departures recorded in the configuration files):
   * byte tokens (UTF-8 bytes + 3, 0 = pad) folded into the vocabulary;
-  * no per-head QK-norm;
   * embedder: masked mean-pool of the last hidden state, L2-normalised;
   * judge: sigmoid(mean over d_model of the last position's hidden state);
   * neither applies the final norm.
 """
 from __future__ import annotations
 
-import functools
-import math
+import importlib.util
+import os
+import re
+from types import ModuleType
+from typing import NamedTuple
 
 import numpy as np
 
@@ -44,51 +63,6 @@ def byte_tokens(texts, max_len: int, vocab: int) -> np.ndarray:
         raw = np.frombuffer(t.encode("utf-8")[:max_len], np.uint8)
         out[i, :len(raw)] = raw.astype(np.int32) + 3
     return out % vocab
-
-
-def dims(cfg: dict) -> dict:
-    """The model sizes of a configuration file (HF config.json keys)."""
-    return dict(
-        d=int(cfg["hidden_size"]), layers=int(cfg["num_hidden_layers"]),
-        heads=int(cfg["num_attention_heads"]),
-        kv=int(cfg["num_key_value_heads"]), dh=int(cfg["head_dim"]),
-        ff=int(cfg["intermediate_size"]), vocab=int(cfg["vocab_size"]),
-        theta=float(cfg["rope_theta"]), eps=float(cfg["rms_norm_eps"]),
-    )
-
-
-def make_weights(cfg: dict, seed: int):
-    """The served parameter tree, drawn on the default device in one
-    jitted call: bfloat16 matrices with std 1/sqrt(fan_in) (embedding
-    0.02), float32 norm scales near 1 (so a dropped scale shows)."""
-    m = dims(cfg)
-    d, n, hq, hk, ff = m["d"], m["layers"], m["heads"] * m["dh"], \
-        m["kv"] * m["dh"], m["ff"]
-    mats = {"wq": (d, hq), "wk": (d, hk), "wv": (d, hk), "wo": (hq, d),
-            "w_gate": (d, ff), "w_up": (d, ff), "w_down": (ff, d)}
-
-    @jax.jit
-    def draw(key):
-        ks = jax.random.split(key, len(mats) + 4)
-
-        def mat(k, shape, std):
-            x = jax.random.normal(k, shape, jnp.float32) * std
-            return x.astype(jnp.bfloat16)
-
-        def scale(k, shape):
-            return 1.0 + 0.1 * jax.random.normal(k, shape, jnp.float32)
-
-        w = {name: mat(ks[i], (n, *shape), 1.0 / math.sqrt(shape[0]))
-             for i, (name, shape) in enumerate(sorted(mats.items()))}
-        blk = {"norm1": {"scale": scale(ks[-4], (n, d))},
-               "norm2": {"scale": scale(ks[-3], (n, d))},
-               "mixer": {k: w[k] for k in ("wq", "wk", "wv", "wo")},
-               "ffn": {k: w[k] for k in ("w_gate", "w_up", "w_down")}}
-        return {"embed": {"table": mat(ks[-2], (m["vocab"], d), 0.02)},
-                "final_norm": {"scale": scale(ks[-1], (d,))},
-                "blocks": {"l0": blk}}
-
-    return draw(jax_key(seed))
 
 
 def _fp8(x):
@@ -115,66 +89,56 @@ def _rope(x, pos, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-@functools.partial(jax.jit, static_argnames=("mt", "control"))
-def _layer(x, blocks, li, *, mt, control):
-    """Layer ``li`` of the stacked weights, upcast to float32: a pre-norm
-    decoder layer, causal GQA attention with RoPE then a SwiGLU
-    feed-forward, each added to the residual stream."""
-    m = dict(mt)
-    w = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
-        a, li, 0, keepdims=False).astype(jnp.float32), blocks)
-    b, s, d = x.shape
-    hq, hk, dh = m["heads"], m["kv"], m["dh"]
-    h = _rms(x, w["norm1"]["scale"], m["eps"])
-    q = _mm(h, w["mixer"]["wq"], control).reshape(b, s, hq, dh)
-    k = _mm(h, w["mixer"]["wk"], control).reshape(b, s, hk, dh)
-    v = _mm(h, w["mixer"]["wv"], control).reshape(b, s, hk, dh)
-    pos = jnp.arange(s)
-    q, k = _rope(q, pos, m["theta"]), _rope(k, pos, m["theta"])
-    k = jnp.repeat(k, hq // hk, axis=2)        # query head i reads kv i // g
-    v = jnp.repeat(v, hq // hk, axis=2)
-    if control:
-        q, k, v = _fp8(q), _fp8(k), _fp8(v)
-    sc = jnp.einsum("bqhd,bkhd->bhqk", q, k, precision=HIGHEST) \
-        / math.sqrt(dh)
-    causal = jnp.arange(s)[None, :] <= jnp.arange(s)[:, None]
-    p = jax.nn.softmax(jnp.where(causal, sc, -1e30), axis=-1)
-    if control:
-        p, v = _fp8(p), _fp8(v)
-    o = jnp.einsum("bhqk,bkhd->bqhd", p, v, precision=HIGHEST)
-    x = x + _mm(o.reshape(b, s, hq * dh), w["mixer"]["wo"], control)
-    h = _rms(x, w["norm2"]["scale"], m["eps"])
-    g = jax.nn.silu(_mm(h, w["ffn"]["w_gate"], control))
-    u = _mm(h, w["ffn"]["w_up"], control)
-    return x + _mm(g * u, w["ffn"]["w_down"], control)
+class Model(NamedTuple):
+    """One served model: its published config and its family's file."""
+    hf: dict
+    family: ModuleType
 
 
-def hidden(w, tokens: np.ndarray, cfg: dict, control: bool = False):
-    """Last hidden state (B, S, D) float32 of the plain forward, one
-    layer at a time."""
-    m = dims(cfg)
-    mt = tuple(sorted(m.items()))
-    table = w["embed"]["table"]
-    x = jnp.take(table, jnp.asarray(tokens), axis=0).astype(jnp.float32)
-    if control:
-        x = _fp8(x)
-    blocks = w["blocks"]["l0"]
-    for li in range(m["layers"]):
-        x = _layer(x, blocks, jnp.int32(li), mt=mt, control=control)
-    return x
+_FAMILY = re.compile(r"^[A-Za-z_][A-Za-z0-9_]{0,63}$")
 
 
-def embed_ref(w, tokens: np.ndarray, cfg: dict, control: bool = False):
+def family(model_type, where: str) -> ModuleType:
+    """The family file ``<where>/<model_type>.py``; an unknown
+    ``model_type`` ends the run."""
+    path = os.path.join(where, f"{model_type}.py")
+    if not isinstance(model_type, str) or not _FAMILY.match(model_type) \
+            or not os.path.isfile(path):
+        raise SystemExit(f"no model family for model_type {model_type!r}: "
+                         f"the benchmark has no {path}")
+    spec = importlib.util.spec_from_file_location(
+        "model_family_" + model_type, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def served_models(cfg: dict, where: str) -> dict:
+    """``{"judge": Model, "embedder": Model}`` of a configuration file:
+    its ``judge_model`` and ``embedder_model`` where it has them, its
+    own top-level config otherwise. Each family file is loaded once."""
+    loaded: dict = {}
+    out = {}
+    for role in ("judge", "embedder"):
+        hf = cfg.get(role + "_model", cfg)
+        mt = hf.get("model_type")
+        if mt not in loaded:
+            loaded[mt] = family(mt, where)
+        out[role] = Model(hf, loaded[mt])
+    return out
+
+
+def embed_ref(model: Model, w, tokens: np.ndarray, control: bool = False):
     """(B, D) unit vectors: masked mean-pool of the last hidden state."""
-    x = hidden(w, tokens, cfg, control)
+    x = model.family.hidden(w, tokens, model.hf, control)
     mask = (jnp.asarray(tokens) > 0).astype(jnp.float32)[..., None]
     pooled = jnp.sum(x * mask, 1) / jnp.maximum(jnp.sum(mask, 1), 1.0)
     return np.asarray(pooled / jnp.maximum(
         jnp.linalg.norm(pooled, axis=-1, keepdims=True), 1e-6))
 
 
-def judge_logit_ref(w, tokens: np.ndarray, cfg: dict,
+def judge_logit_ref(model: Model, w, tokens: np.ndarray,
                     control: bool = False) -> np.ndarray:
     """(B,) judge logits: mean over d_model of the last position."""
-    x = hidden(w, tokens, cfg, control)
+    x = model.family.hidden(w, tokens, model.hf, control)
     return np.asarray(jnp.mean(x[:, -1, :], axis=-1), np.float64)

@@ -27,7 +27,7 @@ line per seed on stdout: the run's own result, and under ``program``
 A bound tracer changes the pass it times: ``seri.h2d`` waits for the
 copy to land before the kernel is dispatched, which the unbound pass
 overlaps. The run's own numbers (its end-to-end metrics, or under
-``--profile`` its eight per-layer metrics: ``stage1_ms``,
+``--profile`` its per-layer metrics: ``stage1_ms``,
 ``device_idle_share`` and ``lookup_mfu`` most) are those of the bound
 program; the benchmark's own runs never bind it.
 """

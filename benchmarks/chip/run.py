@@ -7,10 +7,12 @@
 Everything a cell is made of is found by name from ``BENCHMARK.json`` at
 the root of the checkout: its configuration file (``configs/``), its
 traffic file (``traffic/<traffic>.json``) and the generator it names
-(``traffic/gen/<generator>.py``, driven by ``traffic/gen/mix.py``) and,
-in a traced run, one reader per per-layer metric
-(``layer_metrics/<metric>.py``). A new cell, configuration, mix or metric
-is new files and new entries, never an edit.
+(``traffic/gen/<generator>.py``, driven by ``traffic/gen/mix.py``), the
+family file of its judge's and its embedder's ``model_type``
+(``models/<model_type>.py``, see ``model_ref.py``) and, in a traced run,
+one reader per per-layer metric (``layer_metrics/<metric>.py``). A new
+cell, configuration, architecture, mix or metric is new files and new
+entries, never an edit.
 
 A run: make the inputs and weights from ``--seed``; fill the index; warm
 every shape the window uses and replay the traffic's head (all of that is
@@ -46,6 +48,7 @@ sys.path.insert(1, os.path.join(ROOT, "src"))
 
 import check  # noqa: E402
 import counts  # noqa: E402
+import model_ref  # noqa: E402
 import served  # noqa: E402
 import tracing  # noqa: E402
 from traffic.gen import mix as traffic_mix  # noqa: E402
@@ -91,7 +94,9 @@ def load_cell(name: str, root: str = ROOT) -> dict:
             readers[m["name"]] = (m, mod.read)
     generate = traffic_mix.generator(traffic["generator"],
                                      os.path.join(chip, "traffic", "gen"))
+    models = model_ref.served_models(cfg, os.path.join(chip, "models"))
     return dict(cell=cell, cfg=cfg, traffic=traffic, generate=generate,
+                models=models,
                 end_to_end=[m for m in bench["end_to_end"] if mine(m)],
                 per_layer=readers)
 
@@ -154,7 +159,7 @@ def set_up(c: dict, seed: int, seconds: float, *, trace: bool = False,
     bg = served.background_rows(n_bg, dep["index"]["dim"], seed + 3)
     mark("background rows")
     spans = served.Spans(annotate=trace)
-    sut = served.Served(c["cfg"], mix, seed, spans)
+    sut = served.Served(c["cfg"], c["models"], mix, seed, spans)
     mark("models")
     sut.fill(bg)
     del bg
@@ -240,9 +245,12 @@ def run(name: str, seed: int, seconds: float, trace: bool, *,
     # --- per-layer
     stats = sut.cache.seri.pipeline.stats
     wspans = spans.rows[n_spans0:]
+    judge_calls = sut.judge.calls[n_judge0:]
     run_rec = dict(
         spans=wspans, cfg=cfg, passes=[p["shape"] for p in sut.log.passes],
+        judge=c["models"]["judge"], embedder=c["models"]["embedder"],
         embedded=n_embedded(sut.embeds),
+        judge_calls=[len(q) for q, _, _ in judge_calls],
         judged_pairs=stats.judged_pairs - pairs0,
         judge_batches=stats.judge_batches - batches0,
         peaks=counts.peaks(dev["kind"]) if dev["platform"] == "tpu"
@@ -266,15 +274,16 @@ def run(name: str, seed: int, seconds: float, trace: bool, *,
                            "refresh_every in the configuration")
     dec_bad = check.decisions(mix.world, dep, sut.decisions.calls, win)
     ilog, embeds = sut.log, sut.embeds
-    judge_calls = sut.judge.calls[n_judge0:]
     judge_w, embed_w = sut.judge_w, sut.embed_w
     sut.cache = sut.index = ix = None
     t_ref = time.time()
     rng = np.random.default_rng(seed + 7)
     s1, s1_n = check.stage1(snap, ilog, router, control)
     del snap
-    emb = check.embed_gap(cfg, embed_w, embeds, rng, control)
-    jud = check.judge_gap(cfg, judge_w, judge_calls, rng, control)
+    emb = check.embed_gap(c["models"]["embedder"], dep["embed_max_len"],
+                          embed_w, embeds, rng, control)
+    jud = check.judge_gap(c["models"]["judge"], dep["judge_max_len"],
+                          judge_w, judge_calls, rng, control)
     limits = dict(cfg["limits"], stage1_rows=0, decisions=0,
                   inserts_lost=0, compiles=0)
 

@@ -164,23 +164,6 @@ def scan_shape(index, b: int):
                 itemsize=index.emb.dtype.itemsize)
 
 
-def program_model(cfg: dict):
-    """The program's ModelConfig for a configuration file."""
-    from repro.nn.config import AttnConfig, LayerSpec, ModelConfig
-
-    m = model_ref.dims(cfg)
-    if m["layers"] < 2:
-        raise ValueError("the served models stack layers: need >= 2")
-    attn = AttnConfig(n_heads=m["heads"], n_kv_heads=m["kv"],
-                      head_dim=m["dh"], rope_theta=m["theta"])
-    return ModelConfig(
-        name=cfg["name"], family="dense", d_model=m["d"],
-        vocab_size=m["vocab"], n_repeat=m["layers"], norm_eps=m["eps"],
-        blocks=(LayerSpec(kind="attn", attn=attn, d_ff=m["ff"]),),
-        tie_embeddings=bool(cfg["tie_word_embeddings"]),
-        param_dtype="bfloat16", compute_dtype="bfloat16")
-
-
 def _swap_params(model, weights) -> None:
     """Serve the benchmark's weights: same tree, shapes and dtypes as the
     program's own initialisation, which is dropped first."""
@@ -196,10 +179,11 @@ def _swap_params(model, weights) -> None:
 
 
 class Served:
-    """One run's system under test, built from a configuration file and
-    a traffic mix."""
+    """One run's system under test, built from a configuration file, its
+    judge and embedder (``model_ref.served_models``) and a traffic mix."""
 
-    def __init__(self, cfg: dict, mix, seed: int, spans: Spans):
+    def __init__(self, cfg: dict, models: dict, mix, seed: int,
+                 spans: Spans):
         from repro.core.cache import make_cache
         from repro.core.clustering import ClusterConfig
         from repro.core.embedder import ModelEmbedder
@@ -210,13 +194,15 @@ class Served:
         dep = cfg["deployment"]
         self.cfg, self.mix, self.seed, self.spans = cfg, mix, seed, spans
         self.world = mix.world
-        pm = program_model(cfg)
-        self.judge_w = model_ref.make_weights(cfg, seed + 1)
-        self.embed_w = model_ref.make_weights(cfg, seed + 2)
-        judge = ModelJudge(cfg=pm, max_len=dep["judge_max_len"], seed=0)
+        jm, em = models["judge"], models["embedder"]
+        self.judge_w = jm.family.make_weights(jm.hf, seed + 1)
+        self.embed_w = em.family.make_weights(em.hf, seed + 2)
+        judge = ModelJudge(cfg=jm.family.program_config(jm.hf, cfg["name"]),
+                           max_len=dep["judge_max_len"], seed=0)
         _swap_params(judge, self.judge_w)
-        self.embedder = ModelEmbedder(cfg=pm, max_len=dep["embed_max_len"],
-                                      seed=0)
+        self.embedder = ModelEmbedder(
+            cfg=em.family.program_config(em.hf, cfg["name"]),
+            max_len=dep["embed_max_len"], seed=0)
         _swap_params(self.embedder, self.embed_w)
         self.judge = TimedJudge(judge, spans)
         self.decisions = RecordedDecisions(OracleJudge(

@@ -1,11 +1,16 @@
 """FLOP and byte counts against shapes worked out by hand."""
+import json
+import os
+
 import pytest
 
 import counts
+import model_ref
+import tiny
 
-QWEN = {"hidden_size": 1024, "num_hidden_layers": 28,
-        "num_attention_heads": 16, "num_key_value_heads": 8,
-        "head_dim": 128, "intermediate_size": 3072}
+with open(os.path.join(tiny.CHIP, "configs", "code-brute-256k.json")) as f:
+    QWEN = json.load(f)
+QWEN3 = model_ref.family("qwen3", os.path.join(tiny.CHIP, "models"))
 
 
 def test_lm_forward_flops_qwen3_0_6b_at_128_tokens():
@@ -13,8 +18,17 @@ def test_lm_forward_flops_qwen3_0_6b_at_128_tokens():
     per_tok = 2 * 1024 * (2 * 2048 + 2 * 1024) + 2 * 3 * 1024 * 3072
     assert per_tok == 31_457_280
     attn = 2 * 2 * (128 * 129 // 2) * 2048     # causal q.k and p.v
-    assert counts.lm_forward_flops(QWEN, 128) == 28 * (128 * per_tok + attn)
-    assert counts.lm_forward_flops(QWEN, 128) == 114_636_619_776
+    assert QWEN3.forward_flops(QWEN, 128) == 28 * (128 * per_tok + attn)
+    assert QWEN3.forward_flops(QWEN, 128) == 114_636_619_776
+
+
+def test_weight_bytes_qwen3_0_6b():
+    # per layer: bf16 q,o 1024x2048, k,v 1024x1024, FFN 3 x 1024x3072,
+    # and two float32 norm scales of 1024; no embedding, no final norm
+    mats = 2 * 1024 * 2048 + 2 * 1024 * 1024 + 3 * 1024 * 3072
+    per_layer = 2 * mats + 4 * 2 * 1024
+    assert per_layer == 31_465_472
+    assert QWEN3.weight_bytes(QWEN) == 28 * per_layer == 881_033_216
 
 
 def test_brute_scan_counts():
@@ -63,3 +77,28 @@ def test_scan_work_reads_a_logged_pass():
 def test_unknown_device_kind_is_an_error():
     with pytest.raises(KeyError):
         counts.peaks("TPU v9 imaginary")
+
+
+def test_judge_roofline_reads_each_prefill_call():
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "judge_roofline", os.path.join(tiny.CHIP, "layer_metrics",
+                                       "judge_roofline.py"))
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+    peak = counts.peaks("TPU v5 lite")
+    trace = {"op_s": {"jit_score:fusion.1": 0.3, "jit_score:fusion.2": 0.2,
+                      "jit_encode:fusion.1": 9.0}, "kernels": []}
+    run = {"trace": trace, "judge_calls": [1, 40], "peaks": peak,
+           "cfg": QWEN, "judge": model_ref.Model(QWEN, QWEN3)}
+    flops, nbytes = 114_636_619_776, 881_033_216
+    # 40 pairs go in two calls, of 32 and 8; one pair reads the weights
+    # for longer than it computes
+    assert nbytes / peak["hbm_bytes_per_s"] > flops / peak["bf16_flops_per_s"]
+    need = nbytes / peak["hbm_bytes_per_s"] \
+        + (32 + 8) * flops / peak["bf16_flops_per_s"]
+    assert reader.read(run) == pytest.approx(100 * need / 0.5)
+    assert reader.read(dict(run, trace=None)) is None
+    assert reader.read(dict(run, judge_calls=[])) is None
+    assert reader.read(dict(run, trace=dict(trace, op_s={}))) is None

@@ -123,6 +123,39 @@ def test_answers_are_judged_as_served(root, monkeypatch):
     assert out["correct"], out["compared"]
 
 
+def _harness_files(chip) -> dict:
+    """The bytes of every file of the harness that a new cell reuses."""
+    paths = ["run.py", "served.py", "check.py", "tracing.py", "counts.py",
+             "model_ref.py", "traffic/gen/mix.py", "traffic/gen/swe.py",
+             "traffic/gen/zipf.py"]
+    for d in ("models", "layer_metrics"):
+        paths += sorted(f"{d}/{p}" for p in os.listdir(os.path.join(chip, d))
+                        if p.endswith(".py"))
+    assert "models/qwen3.py" in paths
+    return {p: open(os.path.join(chip, p), "rb").read() for p in paths}
+
+
+def _add_cell(new, cfg, traffic, name, per_layer=()):
+    """Configuration file ``cfg`` and cell ``name`` on mix ``traffic``
+    (and per-layer metrics) as new entries of ``new``'s benchmark."""
+    chip = os.path.join(new, "benchmarks", "chip")
+    with open(os.path.join(chip, "configs", cfg["name"] + ".json"),
+              "w") as f:
+        json.dump(cfg, f)
+    with open(os.path.join(new, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    bench["configs"].append({
+        "name": cfg["name"], "source": "tiny",
+        "file": f"benchmarks/chip/configs/{cfg['name']}.json",
+        "reduced": [], "why": "tiny"})
+    bench["workloads"].append({"name": name, "config": cfg["name"],
+                               "traffic": traffic, "chips": 1,
+                               "why": "tiny"})
+    bench["per_layer"].extend(per_layer)
+    with open(os.path.join(new, "BENCHMARK.json"), "w") as f:
+        json.dump(bench, f)
+
+
 def test_a_new_cell_is_found_by_name(root, tmp_path):
     """A configuration, a mix with a generator of its own and a
     per-layer metric added as new files and new entries, with no file of
@@ -130,14 +163,9 @@ def test_a_new_cell_is_found_by_name(root, tmp_path):
     new = str(tmp_path / "root")
     shutil.copytree(root, new, symlinks=True)
     chip = os.path.join(new, "benchmarks", "chip")
-    before = {p: open(os.path.join(chip, p), "rb").read()
-              for p in ("run.py", "served.py", "check.py", "tracing.py",
-                        "counts.py", "traffic/gen/mix.py",
-                        "traffic/gen/swe.py", "traffic/gen/zipf.py")}
+    before = _harness_files(chip)
     cfg = tiny.tiny_config("tiny-new", router=False)
     cfg["deployment"]["index"]["rows"] = 2048
-    with open(os.path.join(chip, "configs", "tiny-new.json"), "w") as f:
-        json.dump(cfg, f)
     with open(os.path.join(chip, "traffic", "tiny-swe.json")) as f:
         mix = json.load(f)
     mix.update(rate_per_s=40.0, generator="every_other")
@@ -154,20 +182,10 @@ def test_a_new_cell_is_found_by_name(root, tmp_path):
               "w") as f:
         f.write("def read(run):\n"
                 "    return len(run['passes']) / 2.0\n")
-    with open(os.path.join(new, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    bench["configs"].append({"name": "tiny-new", "source": "tiny",
-                             "file": "benchmarks/chip/configs/tiny-new.json",
-                             "reduced": [], "why": "tiny"})
-    bench["workloads"].append({"name": "tiny-new.slow",
-                               "config": "tiny-new", "traffic": "tiny-slow",
-                               "chips": 1, "why": "tiny"})
-    bench["per_layer"].append({"name": "batches_per_s", "unit": "1/s",
-                               "better": "higher", "source": "host_clock",
-                               "layer": "cache", "moves": "lookups_per_s",
-                               "workloads": ["tiny-new.slow"]})
-    with open(os.path.join(new, "BENCHMARK.json"), "w") as f:
-        json.dump(bench, f)
+    _add_cell(new, cfg, "tiny-slow", "tiny-new.slow", per_layer=[{
+        "name": "batches_per_s", "unit": "1/s", "better": "higher",
+        "source": "host_clock", "layer": "cache", "moves": "lookups_per_s",
+        "workloads": ["tiny-new.slow"]}])
     c = run.load_cell("tiny-new.slow", new)
     assert c["cfg"]["deployment"]["index"]["rows"] == 2048
     assert c["traffic"]["rate_per_s"] == 40.0
@@ -179,6 +197,58 @@ def test_a_new_cell_is_found_by_name(root, tmp_path):
     assert out["correct"] and 20 <= out["attempted"] <= 60
     for p, data in before.items():
         assert open(os.path.join(chip, p), "rb").read() == data
+
+
+def _tiny_hf(cfg, **sizes) -> dict:
+    """A published-config-shaped copy of a tiny configuration's model."""
+    hf = {k: v for k, v in cfg.items()
+          if k not in ("name", "deployment", "limits", "departures",
+                       "assumed")}
+    hf.update(sizes)
+    return hf
+
+
+def test_a_new_architecture_is_a_new_file(root, tmp_path):
+    """A model family added as a new file under ``models/``, named by
+    the ``model_type`` of a judge and an embedder of different widths in
+    a new configuration: the run builds each from its own config, comes
+    out correct, and no file of the benchmark is edited."""
+    new = str(tmp_path / "root")
+    shutil.copytree(root, new, symlinks=True)
+    chip = os.path.join(new, "benchmarks", "chip")
+    before = _harness_files(chip)
+    with open(os.path.join(chip, "models", "qwen3.py")) as f:
+        family = f.read()
+    with open(os.path.join(chip, "models", "qwen3_twin.py"), "w") as f:
+        f.write(family)
+    cfg = tiny.tiny_config("tiny-two", router=False)
+    cfg["judge_model"] = _tiny_hf(cfg, model_type="qwen3_twin",
+                                  hidden_size=96, intermediate_size=160)
+    cfg["embedder_model"] = _tiny_hf(cfg, hidden_size=48, head_dim=8)
+    _add_cell(new, cfg, "tiny-swe", "tiny-two.swe")
+    c = run.load_cell("tiny-two.swe", new)
+    jm, em = c["models"]["judge"], c["models"]["embedder"]
+    assert jm.family.__name__ == "model_family_qwen3_twin"
+    assert em.family.__name__ == "model_family_qwen3"
+    assert jm.family.program_config(jm.hf, "j").d_model == 96
+    assert em.family.program_config(em.hf, "e").d_model == 48
+    out = run.run("tiny-two.swe", 11, 2.0, False, root=new, chips=False,
+                  t_start=time.time())
+    assert out["correct"], out["compared"]
+    assert out["attempted"] > 0
+    for p, data in before.items():
+        assert open(os.path.join(chip, p), "rb").read() == data
+
+
+def test_an_unknown_model_type_ends_the_run(root, tmp_path):
+    new = str(tmp_path / "root")
+    shutil.copytree(root, new, symlinks=True)
+    cfg = tiny.tiny_config("tiny-odd", router=False)
+    cfg["judge_model"] = _tiny_hf(cfg, model_type="no_such_family")
+    _add_cell(new, cfg, "tiny-swe", "tiny-odd.swe")
+    with pytest.raises(SystemExit, match="no_such_family"):
+        run.run("tiny-odd.swe", 11, 2.0, False, root=new, chips=False,
+                t_start=time.time())
 
 
 def _cmd(cwd, env_extra=None):

@@ -37,6 +37,9 @@ def test_summary_of_the_window():
     assert s["kernels"] == [OPS]
     assert tracing.kernel_seconds(s, r"ann_topk") == pytest.approx(2.0)
     assert tracing.kernel_seconds(s, r"fusion") == 0.0   # not a kernel
+    # every op of a module, kernel or not
+    assert tracing.op_seconds(s, r"^jit_score:") == pytest.approx(1.5)
+    assert tracing.op_seconds(s, r"^jit_") == pytest.approx(2.0 + 1.5 + 1.5)
     # idle [2.5, 4] in lookup, [5, 7] mostly insert (mid 6.0),
     # [8, 10.5] in no span
     assert s["idle_by"]["lookup"] == pytest.approx(1.5)

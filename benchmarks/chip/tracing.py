@@ -158,6 +158,14 @@ def kernel_seconds(summary: dict, pattern: str) -> float:
                      if rx.search(k)))
 
 
+def op_seconds(summary: dict, pattern: str) -> float:
+    """Summed device time of every op, kernel or not, whose "module:op"
+    name matches ``pattern``: with ``^<module>:``, what a jitted
+    function's calls kept the chip busy."""
+    rx = re.compile(pattern)
+    return float(sum(s for k, s in summary["op_s"].items() if rx.search(k)))
+
+
 def breakdown(summary: dict) -> dict:
     """The ten device ops that took most time and the idle time by what
     the host was doing, as ``[[name, seconds], ...]``."""
