@@ -268,7 +268,7 @@ def phase_kernels(n: int = 1 << 20, d: int = 1024, batches=(8, 64),
     # fp32 brute force: the 4 GiB matrix placed once
     emb, act = jax.device_put(vidx.emb), jax.device_put(vidx.active)
     for b, q in qs_by_b.items():
-        vals, rows = ann_topk_jit(emb, act, jnp.asarray(q), k)
+        vals, rows = ann_topk_jit(emb, act, q, k)
         r_rows, r_vals = vidx.numpy_brute(q, k)
         compare("ann_topk", f"N={n} D={d} B={b} k={k}", rows, vals,
                 r_rows, r_vals, fp32_scores(vidx.emb, q), tol)

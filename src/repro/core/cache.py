@@ -166,7 +166,8 @@ class CortexCache:
         ix = self.seri.index
         self.scan.note_pass(ix.last_scanned, ix.last_scanned_max_shard,
                             ix.last_h2d_bytes, ix.last_full_uploads,
-                            ix.last_synced_rows)
+                            ix.last_synced_rows, ix.last_d2h_waits,
+                            ix.last_d2h_bytes)
         out = []
         for se_ids, sims in found:
             # revalidating rows are KNOWN stale (change-feed notice,

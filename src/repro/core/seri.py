@@ -89,6 +89,10 @@ class RowIndex:
         self.last_h2d_bytes = 0
         self.last_full_uploads = 0
         self.last_synced_rows = 0
+        # the times it blocked on a copy back from the device, and the
+        # bytes those copies brought (ScanMetrics.d2h_waits / d2h_bytes)
+        self.last_d2h_waits = 0
+        self.last_d2h_bytes = 0
         # the device mirror of _mirrored() (kernel backend, built by the
         # first brute pass) and the rows changed on the host since its
         # last sync
@@ -166,10 +170,13 @@ class RowIndex:
 
     def _new_pass(self) -> None:
         """Zero the per-pass transfer counters (``last_h2d_bytes``,
-        ``last_full_uploads``, ``last_synced_rows``)."""
+        ``last_full_uploads``, ``last_synced_rows``, ``last_d2h_waits``,
+        ``last_d2h_bytes``)."""
         self.last_h2d_bytes = 0
         self.last_full_uploads = 0
         self.last_synced_rows = 0
+        self.last_d2h_waits = 0
+        self.last_d2h_bytes = 0
 
     def _sync_mirror(self) -> tuple:
         """The device mirror of ``_mirrored()``, brought up to date.
@@ -217,8 +224,9 @@ class RowIndex:
         layout of a sharded scan), so the kernel wrappers receive device
         arrays. With ``mirror`` the device mirror of the brute scan's row
         arrays comes first, synced by its dirty rows (``_sync_mirror``):
-        a brute pass sends only those rows and its queries, not the
-        index. Counts the bytes into ``last_h2d_bytes``. Under an
+        a brute pass sends only those rows and its queries, padded on
+        the host, not the index. Counts the bytes into
+        ``last_h2d_bytes``. Under an
         enabled tracer the ``seri.h2d`` span waits for the copies to
         land, so that it holds the whole transfer; untraced, they stay
         asynchronous and the kernel waits for them as it always did."""
@@ -232,6 +240,16 @@ class RowIndex:
             if tr.enabled:
                 jax.block_until_ready(out)
         self.last_h2d_bytes += sum(x.nbytes for x in put)
+        return out
+
+    def _fetch(self, *arrays) -> tuple:
+        """Copy a kernel pass's device results to the host with one
+        blocking wait: ``jax.device_get`` starts every copy before it
+        waits for any. Counts the wait into ``last_d2h_waits`` and the
+        bytes into ``last_d2h_bytes``."""
+        out = jax.device_get(arrays)
+        self.last_d2h_waits += 1
+        self.last_d2h_bytes += sum(x.nbytes for x in out)
         return out
 
     def add_batch(self, se_ids, embeddings) -> np.ndarray:
@@ -403,9 +421,11 @@ class VectorIndex(RowIndex):
         self.emb = np.zeros((capacity, dim), np.float32)
         if self.backend == "kernel":
             from repro.kernels.ops import (
-                ann_topk_ivf_jit, ann_topk_ivf_sharded_jit, ann_topk_jit)
+                ann_topk, ann_topk_ivf_jit, ann_topk_ivf_sharded_jit,
+                pad_queries)
 
-            self._kernel_fn = ann_topk_jit
+            self._pad_queries = pad_queries
+            self._kernel_fn = ann_topk
             self._ivf_kernel_fn = ann_topk_ivf_jit
             self._ivf_sharded_fn = ann_topk_ivf_sharded_jit
 
@@ -480,8 +500,9 @@ class VectorIndex(RowIndex):
                          bucket_rows, bucket_valid, q)
         with tr.wall("seri.scan"):
             sims, rows, sel, en = self._ivf_kernel_fn(*args, nprobe, k)
-            probed = np.unique(np.asarray(sel)[np.asarray(en) > 0])
-            rows, sims = np.asarray(rows), np.asarray(sims)
+            (sel,), (en,) = self._fetch(sel), self._fetch(en)
+            probed = np.unique(sel[en > 0])
+            (rows,), (sims,) = self._fetch(rows), self._fetch(sims)
         self.last_scanned = int(live.sum() + rt.counts[probed].sum())
         self.last_scanned_max_shard = self.last_scanned
         return rows, sims
@@ -512,8 +533,9 @@ class VectorIndex(RowIndex):
                 cent, live_d, layout, shard_rows, shard_valid, bounds, qd,
                 nprobe, k,
             )
-            probed = np.unique(np.asarray(sel)[np.asarray(en) > 0])
-            rows, sims = np.asarray(rows), np.asarray(sims)
+            (sel,), (en,) = self._fetch(sel), self._fetch(en)
+            probed = np.unique(sel[en > 0])
+            (rows,), (sims,) = self._fetch(rows), self._fetch(sims)
         n_cent = int(live.sum())
         per_shard = np.bincount(
             rt.shard_of[probed], weights=rt.counts[probed],
@@ -523,11 +545,19 @@ class VectorIndex(RowIndex):
         return rows, sims
 
     def _search_brute(self, q: np.ndarray, k: int):
+        """The brute scan of every row. On the kernel backend a pass is
+        one put, one dispatch and one fetch: the queries are zero-padded
+        on the host to the kernel's aligned block (``pad_queries``) and
+        put with the mirror's dirty rows, the jitted ``ann_topk`` runs on
+        the resident mirror, its rows and scores come back in one
+        blocking copy, and the pad rows are sliced off on the host."""
         if self.backend == "kernel":
-            emb, active, qd = self._put(q, mirror=True)
+            b = q.shape[0]
+            emb, active, qd = self._put(self._pad_queries(q), mirror=True)
             with self.tracer.wall("seri.scan"):
                 sims, rows = self._kernel_fn(emb, active, qd, k)
-                return np.asarray(rows), np.asarray(sims)
+                rows, sims = self._fetch(rows, sims)
+            return rows[:b], sims[:b]
         with self.tracer.wall("seri.scan"):
             return self.numpy_brute(q, k)
 

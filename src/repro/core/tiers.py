@@ -203,10 +203,12 @@ class QuantIndex(RowIndex):
         args = self._put(rt.centroids, live.astype(np.int32), bq, bscale,
                          bucket_rows, bucket_valid, q, qq, qs)
         vals, rows, sel, en = self._ivf_kernel_fn(*args, nprobe, r)
-        probed = np.unique(np.asarray(sel)[np.asarray(en) > 0])
+        (sel,), (en,) = self._fetch(sel), self._fetch(en)
+        probed = np.unique(sel[en > 0])
         self.last_scanned = int(live.sum() + rt.counts[probed].sum())
         self.last_scanned_max_shard = self.last_scanned
-        return np.asarray(rows), np.asarray(vals)
+        (rows,), (vals,) = self._fetch(rows), self._fetch(vals)
+        return rows, vals
 
     def _coarse_routed_kernel_sharded(self, q, qq, qs, r: int):
         """Shard-parallel quantized coarse scan — the int8 sibling of
@@ -229,20 +231,23 @@ class QuantIndex(RowIndex):
             cent, live_d, bq, bscale, shard_rows, shard_valid, bounds, q,
             qq, qs, nprobe, r,
         )
-        probed = np.unique(np.asarray(sel)[np.asarray(en) > 0])
+        (sel,), (en,) = self._fetch(sel), self._fetch(en)
+        probed = np.unique(sel[en > 0])
         n_cent = int(live.sum())
         per_shard = np.bincount(
             rt.shard_of[probed], weights=rt.counts[probed],
             minlength=rt.n_shards)
         self.last_scanned = n_cent + int(rt.counts[probed].sum())
         self.last_scanned_max_shard = n_cent + int(per_shard.max())
-        return np.asarray(rows), np.asarray(vals)
+        (rows,), (vals,) = self._fetch(rows), self._fetch(vals)
+        return rows, vals
 
     def _coarse_brute(self, qq, qs, r: int):
         if self.backend == "kernel":
             vals, rows = self._kernel_fn(*self._put(qq, qs, mirror=True),
                                          r)
-            return np.asarray(rows), np.asarray(vals)
+            (rows,), (vals,) = self._fetch(rows), self._fetch(vals)
+            return rows, vals
         return self.numpy_coarse_brute(qq, qs, r)
 
     def numpy_coarse_brute(self, qq, qs, r: int):
@@ -637,7 +642,8 @@ class TieredCache(CortexCache):
             self.scan.add_warm_pass(
                 wix.last_scanned, wix.last_scanned_max_shard,
                 wix.last_h2d_bytes, wix.last_full_uploads,
-                wix.last_synced_rows,
+                wix.last_synced_rows, wix.last_d2h_waits,
+                wix.last_d2h_bytes,
             )
             for bi, (wc, wsims) in zip(warm_qi, wfound):
                 # the consult FACT (flowing back through

@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.kernels.ann_topk import ann_topk
 from repro.kernels.ann_topk_ivf import NEG, ann_topk_ivf, ann_topk_ivf_quant
@@ -23,38 +24,40 @@ __all__ = ["ann_topk", "ann_topk_quant", "ann_topk_ivf",
            "ann_topk_ivf_quant_sharded", "flash_attention_fwd",
            "decode_attention", "ann_topk_jit", "ann_topk_quant_jit",
            "ann_topk_ivf_jit", "ann_topk_ivf_quant_jit",
-           "ann_topk_ivf_sharded_jit", "ann_topk_ivf_quant_sharded_jit"]
+           "ann_topk_ivf_sharded_jit", "ann_topk_ivf_quant_sharded_jit",
+           "pad_queries"]
 
 
 _B_ALIGN = 8  # fp32 sublane count: pad the query block to aligned shapes
 
 
+def pad_queries(q: np.ndarray) -> np.ndarray:
+    """A (B, D) host query block zero-padded on the host to a multiple of
+    the fp32 sublane count. The kernel's (B, D) block stays TPU-aligned,
+    jit compiles once per padded size (B = 1, 2, 4 and 8 share one
+    program), and no pad runs on the device. Each query column is
+    reduced independently inside the kernel, so the zero rows change no
+    real result and are sliced off on the host."""
+    pad = (-q.shape[0]) % _B_ALIGN
+    return np.pad(q, ((0, pad), (0, 0))) if pad else q
+
+
 def ann_topk_jit(emb, active, q, k: int = 4):
-    """VectorIndex backend adapter: (D,) or (B, D) queries -> (sims, rows).
+    """Brute stage-1 pass: (D,) or (B, D) host queries -> (sims, rows) on
+    the host, as one put, one dispatch and one fetch: the queries are
+    padded on the host (``pad_queries``), the jitted ``ann_topk`` runs
+    on the padded block, both results come back in one blocking copy,
+    and the pad rows are sliced off on the host.
 
-    The batched cache runtime sends variable-size query blocks (engine
-    micro-batches, DESIGN.md §8); padding B up to a multiple of the fp32
-    sublane count keeps the kernel's (B, D) block shape TPU-aligned and
-    bounds jit retraces to one per padded size. Each query column is
-    reduced independently inside the kernel, so the zero-padded rows are
-    sliced off without affecting real results.
-
-    ``VectorIndex`` passes device arrays: ``emb`` and ``active`` from its
-    device mirror, which stays resident between passes and takes only the
-    rows changed since the last one, and ``q`` put per pass (its
-    ``seri.h2d`` span and ``ScanMetrics.h2d_bytes``), so here the
-    ``jnp.asarray`` calls move nothing; a caller that passes host arrays
-    has them copied here."""
+    ``VectorIndex`` makes the same three steps itself, so that its
+    ``seri.h2d`` span and transfer counters hold them, with ``emb`` and
+    ``active`` from its device mirror. ``emb`` and ``active`` may be
+    device arrays, which move nothing here, or host arrays, which the
+    dispatch copies."""
     single = q.ndim == 1
-    if single:
-        q = q[None]
+    q = np.atleast_2d(np.asarray(q))
     b = q.shape[0]
-    pad = (-b) % _B_ALIGN
-    if pad:
-        q = jnp.pad(jnp.asarray(q), ((0, pad), (0, 0)))
-    vals, rows = ann_topk(
-        jnp.asarray(emb), jnp.asarray(active), jnp.asarray(q), k
-    )
+    vals, rows = jax.device_get(ann_topk(emb, active, pad_queries(q), k))
     vals, rows = vals[:b], rows[:b]
     if single:
         return vals[0], rows[0]
@@ -100,9 +103,9 @@ def ann_topk_ivf_jit(centroids, live, buckets, bucket_rows, bucket_valid,
     index rows (-1 where masked); sel/enabled feed the host's
     rows-scanned accounting.
 
-    As with :func:`ann_topk_jit`, ``VectorIndex`` puts every operand
-    (centroids, live mask, bucket layout, bucket rows and mask, queries)
-    on the device before the call; host arrays are copied here."""
+    ``VectorIndex`` puts every operand (centroids, live mask, bucket
+    layout, bucket rows and mask, queries) on the device before the
+    call; host arrays are copied here."""
     b = q.shape[0]
     pad = (-b) % _B_ALIGN
     q = jnp.asarray(q)

@@ -163,6 +163,9 @@ class ScanMetrics:
     scan's row arrays stay on the device between passes (DESIGN.md §8):
     ``full_uploads`` counts the passes that put them whole, and
     ``synced_rows`` the dirty rows scattered into them in place.
+    ``d2h_waits`` counts the times a pass blocked on a copy back from
+    the device (a brute kernel pass makes one, a routed one four), and
+    ``d2h_bytes`` the bytes those copies brought.
     """
 
     last_rows: int = 0            # rows scanned by the last stage-1 pass
@@ -172,33 +175,42 @@ class ScanMetrics:
     h2d_bytes: int = 0            # cumulative host-to-device bytes
     full_uploads: int = 0         # whole-index puts of the device mirror
     synced_rows: int = 0          # dirty rows scattered into the mirror
+    d2h_waits: int = 0            # blocking device-to-host copies
+    d2h_bytes: int = 0            # cumulative device-to-host bytes
 
     def note_pass(self, rows: int, max_shard_rows: int | None = None,
                   h2d_bytes: int = 0, full_uploads: int = 0,
-                  synced_rows: int = 0) -> None:
+                  synced_rows: int = 0, d2h_waits: int = 0,
+                  d2h_bytes: int = 0) -> None:
         """Record one stage-1 pass. ``max_shard_rows`` defaults to
         ``rows`` (the unsharded index IS one shard)."""
         m = rows if max_shard_rows is None else max_shard_rows
         self.last_rows = int(rows)
         self.last_max_shard_rows = int(m)
-        self._add(rows, m, h2d_bytes, full_uploads, synced_rows)
+        self._add(rows, m, h2d_bytes, full_uploads, synced_rows,
+                  d2h_waits, d2h_bytes)
 
     def add_warm_pass(self, rows: int, max_shard_rows: int | None = None,
                       h2d_bytes: int = 0, full_uploads: int = 0,
-                      synced_rows: int = 0) -> None:
+                      synced_rows: int = 0, d2h_waits: int = 0,
+                      d2h_bytes: int = 0) -> None:
         """Fold a warm-tier consult into the CURRENT pass (§10): the hot
         and warm scans of one flush count as one pass's volume."""
         m = rows if max_shard_rows is None else max_shard_rows
         self.last_rows += int(rows)
         self.last_max_shard_rows += int(m)
-        self._add(rows, m, h2d_bytes, full_uploads, synced_rows)
+        self._add(rows, m, h2d_bytes, full_uploads, synced_rows,
+                  d2h_waits, d2h_bytes)
 
-    def _add(self, rows, m, h2d_bytes, full_uploads, synced_rows) -> None:
+    def _add(self, rows, m, h2d_bytes, full_uploads, synced_rows,
+             d2h_waits, d2h_bytes) -> None:
         self.total_rows += int(rows)
         self.total_max_shard_rows += int(m)
         self.h2d_bytes += int(h2d_bytes)
         self.full_uploads += int(full_uploads)
         self.synced_rows += int(synced_rows)
+        self.d2h_waits += int(d2h_waits)
+        self.d2h_bytes += int(d2h_bytes)
 
 
 class MetricsRegistry:

@@ -1,10 +1,12 @@
 """The kernel backend's device mirror of the brute stage-1 rows
 (DESIGN.md §8, "Resident index"): synced by dirty rows, one compiled
-scatter shape, a whole put only where scattering is not worth it. Pallas
-kernels run interpreted on the CPU."""
+scatter shape, a whole put only where scattering is not worth it; and
+the brute pass around it: queries padded on the host, one put, one
+dispatch, one fetch. Pallas kernels run interpreted on the CPU."""
 from __future__ import annotations
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -14,6 +16,7 @@ from repro.core.judge import OracleJudge
 from repro.core.seri import SYNC_CHUNK, VectorIndex
 from repro.core.tiers import QuantIndex, make_tiered_cache
 from repro.data.world import SemanticWorld
+from repro.kernels.ann_topk import ann_topk
 from repro.kernels.ops import ann_topk_jit
 
 DIM, CAP = 32, 256
@@ -62,10 +65,10 @@ def _mirror_matches(ix, sync=False):
 
 
 def _query_bytes(flavour, q):
-    """What a pass puts of its queries: fp32 rows, or int8 rows and their
-    fp32 scales."""
+    """What a pass puts of its queries: fp32 rows padded on the host to
+    the kernel's 8-row block, or int8 rows and their fp32 scales."""
     b = q.shape[0]
-    return b * DIM * 4 if flavour == "fp32" else b * (DIM + 4)
+    return -(-b // 8) * 8 * DIM * 4 if flavour == "fp32" else b * (DIM + 4)
 
 
 def _same_results(flavour, kr, npy, q):
@@ -166,6 +169,68 @@ def test_a_dirty_set_over_the_threshold_puts_the_whole_arrays(flavour,
     _mirror_matches(kr)
 
 
+def _device_padded_pass(ix, q, k):
+    """The brute pass as the device used to pad it: the queries put as
+    they are, padded and sliced by eager device ops, the rows and the
+    scores fetched one after the other."""
+    b = q.shape[0]
+    emb, active = ix._mirror
+    qd = jnp.pad(jax.device_put(q), ((0, (-b) % 8), (0, 0)))
+    vals, rows = ann_topk(emb, active, qd, k)
+    return np.asarray(rows[:b]), np.asarray(vals[:b])
+
+
+@pytest.mark.parametrize("b", [1, 3, 8, 9, 64])
+def test_the_host_padded_pass_matches_both_references(b):
+    """Exactly the numpy backend's rows, and bit for bit the scores of
+    the same kernel fed a block padded on the device."""
+    rng = np.random.default_rng(b)
+    kr, npy = _pair("fp32")
+    e = _unit(rng, 200)
+    kr.add_batch(np.arange(200), e)
+    npy.add_batch(np.arange(200), e)
+    q = _unit(rng, b)
+    rows, sims = kr._search_brute(q, 4)
+    assert rows.shape == sims.shape == (b, 4)
+    np.testing.assert_array_equal(rows, npy.numpy_brute(q, 4)[0])
+    want_rows, want_sims = _device_padded_pass(kr, q, 4)
+    np.testing.assert_array_equal(rows, want_rows)
+    np.testing.assert_array_equal(sims, want_sims)
+
+
+def test_a_brute_pass_blocks_on_the_device_once():
+    rng = np.random.default_rng(5)
+    kr, _ = _pair("fp32")
+    kr.add_batch(np.arange(100), _unit(rng, 100))
+    for b in (1, 2, 5, 8, 13):
+        kr.search_batch(_unit(rng, b), 4, TAU)
+        assert kr.last_d2h_waits == 1
+        # int32 rows and fp32 scores of the padded block
+        assert kr.last_d2h_bytes == -(-b // 8) * 8 * 4 * (4 + 4)
+
+
+def test_small_batches_share_the_8_row_program(compiles):
+    """After one pass at b = 8, passes at b = 1, 2 and 4 compile
+    nothing: the host pads them to the same block, and no pad or slice
+    runs on the device."""
+    rng = np.random.default_rng(6)
+    kr, npy = _pair("fp32")
+    e = _unit(rng, 150)
+    kr.add_batch(np.arange(150), e)
+    npy.add_batch(np.arange(150), e)
+    kr.search_batch(_unit(rng, 8), 4, TAU)
+    compiles.on, n0 = True, compiles.n
+    try:
+        for b in (1, 2, 4):
+            q = _unit(rng, b)
+            got = kr.search_batch(q, 4, TAU)
+            assert [ids for ids, _ in got] == [
+                ids for ids, _ in npy.search_batch(q, 4, TAU)]
+    finally:
+        compiles.on = False
+    assert compiles.n == n0
+
+
 class _Decide:
     def score_pairs(self, queries, keys):
         return np.full(len(queries), 0.95, np.float32)
@@ -190,7 +255,8 @@ def test_scan_metrics_count_uploads_and_synced_rows():
     cache.insert("new", _unit(rng, 1)[0], "v", now=3.0, **kw)  # evicts one
     cache.lookup_batch(["c"], embs[2:3], now=4.0)
     assert (sc.full_uploads, sc.synced_rows) == (1, 1)
-    assert sc.h2d_bytes == (CAP * (DIM * 4 + 1) + 3 * DIM * 4
+    # three one-query passes, each padded on the host to 8 fp32 rows
+    assert sc.h2d_bytes == (CAP * (DIM * 4 + 1) + 3 * 8 * DIM * 4
                             + SYNC_CHUNK * (DIM * 4 + 1 + 4))
     _mirror_matches(cache.seri.index)
 

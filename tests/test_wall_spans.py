@@ -157,10 +157,12 @@ def test_null_tracer_records_nothing(judge_model, monkeypatch):
 @pytest.mark.parametrize("case", ["first", "clean", "dirty"])
 def test_brute_pass_counts_its_bytes(judge_model, case):
     """The first brute pass puts the whole index; later passes keep it on
-    the device and send their queries and the rows changed since."""
+    the device and send their queries and the rows changed since. The
+    queries are padded on the host to the kernel's 8-row block before
+    the put: three queries move 8 fp32 rows."""
     cache, embs = _cache(judge_model)
     ix = cache.seri.index
-    queries = 3 * DIM * 4
+    queries = 8 * DIM * 4
     want = ix.emb.nbytes + ix.active.nbytes + queries
     if case != "first":
         cache.lookup_batch(["x"], _queries(embs, [5]), now=0.5)
@@ -197,6 +199,29 @@ def test_routed_pass_counts_its_bytes(judge_model, shards):
     assert cache.scan.h2d_bytes - before == want
 
 
+@pytest.mark.parametrize("shards", [0, 1, 2])
+def test_each_pass_counts_its_blocking_fetches(judge_model, shards):
+    """A brute kernel pass (``shards`` 0) waits on the device once, for
+    its rows and scores together; a routed pass waits four times (the
+    probed clusters, their mask, the rows, the scores)."""
+    cfg = None if not shards else ClusterConfig(
+        n_clusters=8, nprobe=2, min_train=256, refresh_every=1 << 20,
+        seed=1, n_shards=shards)
+    cache, embs = _cache(judge_model, cluster=cfg)
+    sc, k = cache.scan, cache.seri.top_k
+    for idx in ([0, 1, 2], [3], [4, 5]):
+        w0, b0 = sc.d2h_waits, sc.d2h_bytes
+        cache.lookup_batch(["q"] * len(idx), _queries(embs, idx), now=1.0)
+        if shards:
+            assert sc.d2h_waits - w0 == 4
+            assert sc.d2h_bytes - b0 > 0
+        else:
+            # int32 rows and fp32 scores of the 8-row padded block
+            assert sc.d2h_waits - w0 == 1
+            assert sc.d2h_bytes - b0 == 8 * k * (4 + 4)
+    assert cache.seri.index.passes["routed" if shards else "brute"] == 3
+
+
 def test_the_numpy_backend_moves_nothing(judge_model):
     cache = make_cache(capacity_bytes=1 << 40, dim=DIM, index_capacity=64,
                        judge=_Decide(), backend="numpy")
@@ -206,6 +231,7 @@ def test_the_numpy_backend_moves_nothing(judge_model):
                        staticity=5, ttl=1e9)
     cache.lookup_batch(["a"], _queries(embs, [0]), now=1.0)
     assert cache.scan.total_rows == 8 and cache.scan.h2d_bytes == 0
+    assert cache.scan.d2h_waits == 0 and cache.scan.d2h_bytes == 0
 
 
 @pytest.mark.parametrize("n,padded,chunks", [(1, 0, 1), (3, 1, 1),
